@@ -2,9 +2,10 @@
 
 Wall times below run the kernels in interpret mode on CPU — meaningful
 only as correctness-path checks and for *relative* launch-structure
-comparisons; the perf-relevant output is the structural accounting: VMEM
-working set per replica vs the 16 MiB budget, and the paper-shape
-throughput model.
+comparisons; the perf-relevant output is the structural accounting: the
+VMEM one replica adds to a kernel tile at the paper's shape, and how many
+replicas the tile budget holds (the kernel's own arithmetic,
+`metropolis_kernel.replica_vmem_bytes`).
 
 The headline comparison (`launch_structure_compare`) times the two sweep
 launch structures the engine can dispatch to, at replica batches
@@ -33,24 +34,10 @@ from benchmarks.common import time_fn, write_bench_json
 from repro.configs.ising_qmc import CONFIG as PAPER
 from repro.core import ising, mt19937 as mt
 from repro.core.engine import SweepEngine
+from repro.kernels import metropolis_kernel as mk
 from repro.kernels import ops
 
-VMEM_BUDGET = 16 * 1024 * 1024
 LANES = 128
-
-
-def vmem_accounting(n: int, L: int, lanes: int = LANES):
-    rows = (L // lanes) * n
-    state_bytes = rows * lanes * 4  # f32
-    arrays = {
-        "spins": state_bytes,
-        "h_space": state_bytes,
-        "h_tau": state_bytes,
-        "mt19937_state": mt.N * lanes * 4,  # fused in-kernel RNG, no uniforms
-        "outputs(3+rng)": 3 * state_bytes + mt.N * lanes * 4,
-    }
-    total = sum(arrays.values())
-    return rows, arrays, total
 
 
 def launch_structure_compare(
@@ -174,16 +161,22 @@ def colored_vs_sequential(B: int = 8, num_sweeps: int = 2):
 
 def run():
     rows_out = []
-    # Paper production shape: 256 layers x 96 spins.
-    rows, arrays, total = vmem_accounting(PAPER.spins_per_layer, PAPER.num_layers)
+    # Paper production shape: 256 layers x 96 spins, a4 kernel.
+    paper = ising.random_layered_model(
+        n=PAPER.spins_per_layer, L=PAPER.num_layers, seed=1
+    )
+    rows = paper.n * paper.L // LANES
+    per = mk.replica_vmem_bytes(
+        rows, paper.n, paper.space_degree + 1, fields_in=True, multi=False
+    )
+    budget = mk.TILE_VMEM_BUDGET
     rows_out.append(
         ("kernel_vmem_paper_shape", 0.0,
-         f"{total/1024:.0f}KiB of {VMEM_BUDGET/1024/1024:.0f}MiB "
-         f"({total/VMEM_BUDGET:.1%}) rows={rows}")
+         f"{per/1024:.0f}KiB per replica, double-buffered, of a "
+         f"{budget >> 20}MiB tile budget ({per/budget:.1%}) rows={rows}")
     )
-    max_replicas = VMEM_BUDGET // total
     rows_out.append(
-        ("kernel_vmem_max_replicas_resident", 0.0, f"{max_replicas}")
+        ("kernel_vmem_max_replicas_resident", 0.0, f"{budget // per}")
     )
     # Launch-structure comparison: fused multi-sweep vs seed per-sweep path.
     compare_rows, records = launch_structure_compare()

@@ -24,6 +24,9 @@ import sys
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     args = sys.argv[1:]
     if "--smoke" in args:
         args = [a for a in args if a != "--smoke"] + ["smoke"]

@@ -18,8 +18,10 @@ The serving claim of DESIGN.md §Service, measured three ways:
   DESIGN.md §Multi-tenancy (packed >= 2x is the ISSUE 4 acceptance bar).
 * mesh-sharded slot pool (cb rung): the SAME equal-budget job mix served
   at D in {1, 2, 4} forced host devices (``make_slot_mesh(D)``, slots =
-  4*D) — each D in its own subprocess because the forced device count is
-  baked into XLA at first import.  Per-job results must hash identically
+  4*D), all in this one process over the devices present (on the CPU,
+  force them: ``XLA_FLAGS=--xla_force_host_platform_device_count=4``;
+  sections that need more devices than are present print a skip row).
+  Per-job results must hash identically
   across D (the DESIGN.md §Mesh bit-exactness contract), and the
   DETERMINISTIC sweep-clock throughput — jobs per global sweep — must
   scale with the pool: at 4x slots the mix drains in 1/4 the sweeps, so
@@ -73,16 +75,13 @@ Run:  PYTHONPATH=src python -m benchmarks.serve_bench
 from __future__ import annotations
 
 import hashlib
-import json
-import os
-import subprocess
-import sys
 import time
 from collections import defaultdict
 
+import jax
 import numpy as np
 
-from benchmarks.common import REPO_ROOT, write_bench_json
+from benchmarks.common import write_bench_json
 from repro.core import ising
 from repro.serve_mc import AnnealJob, PTJob, SampleServer, make_policy
 
@@ -254,26 +253,21 @@ def _compare_section(m, specs, section: str, slot_configs, *, rung: str,
         )
 
 
-_SHARDED_MARK = "SHARDED_RESULT "
+def _have_devices(need: int, section: str, rows) -> bool:
+    """Whether ``need`` devices are present; if not, a skip row says so."""
+    have = len(jax.devices())
+    if have < need:
+        rows.append((section, 0.0,
+                     f"skipped: needs {need} devices, found {have} (on the "
+                     "CPU: XLA_FLAGS=--xla_force_host_platform_device_count"
+                     f"={need})"))
+    return have >= need
 
 
-def _sharded_worker(d: int) -> None:
-    """Child-process body: serve the fixed equal-budget mix on a D-device
-    ("data",) mesh and print one tagged JSON result line.
-
-    Runs in its own process because ``--xla_force_host_platform_device_count``
-    is read once, at first jax initialization — the parent sets XLA_FLAGS
-    in the child's environment before launching it.
-    """
-    import jax
-
+def _serve_sharded(d: int) -> dict:
+    """Serve the fixed equal-budget mix on a D-device ("data",) mesh."""
     from repro.launch.mesh import make_slot_mesh
 
-    if len(jax.devices()) < d:
-        raise SystemExit(
-            f"sharded worker: need {d} devices, see {len(jax.devices())} "
-            "(XLA_FLAGS not applied?)"
-        )
     m = ising.random_layered_model(n=MODEL_N, L=SHARDED_MODEL_L, seed=0, beta=1.0)
     slots = SHARDED_SLOTS_PER_DEVICE * d
     srv = SampleServer(
@@ -314,30 +308,7 @@ def _sharded_worker(d: int) -> None:
         # Sweeps and the hash are deterministic; best-of only de-noises wall.
         if best is None or dt < best["wall_s"]:
             best = out
-    print(_SHARDED_MARK + json.dumps(best))
-
-
-def _spawn_sharded_worker(d: int) -> dict:
-    env = dict(os.environ)
-    flags = [f for f in env.get("XLA_FLAGS", "").split()
-             if "xla_force_host_platform_device_count" not in f]
-    flags.append(f"--xla_force_host_platform_device_count={d}")
-    env["XLA_FLAGS"] = " ".join(flags)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.serve_bench", "--sharded-worker",
-         str(d)],
-        env=env, cwd=REPO_ROOT, capture_output=True, text=True,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"sharded worker D={d} failed "
-            f"(rc={proc.returncode}):\n{proc.stdout}\n{proc.stderr}"
-        )
-    lines = [ln for ln in proc.stdout.splitlines()
-             if ln.startswith(_SHARDED_MARK)]
-    if not lines:
-        raise RuntimeError(f"sharded worker D={d}: no result line\n{proc.stdout}")
-    return json.loads(lines[-1][len(_SHARDED_MARK):])
+    return best
 
 
 def _sharded_section(rows, records):
@@ -348,7 +319,9 @@ def _sharded_section(rows, records):
     bar jobs_per_sweep(D=4) >= 2x D=1 (4x slots drain the equal-budget
     mix in 1/4 the global sweeps, so the true ratio is 4.0 exactly).
     """
-    outs = {d: _spawn_sharded_worker(d) for d in SHARDED_DEVICE_COUNTS}
+    if not _have_devices(max(SHARDED_DEVICE_COUNTS), "serve_sharded", rows):
+        return
+    outs = {d: _serve_sharded(d) for d in SHARDED_DEVICE_COUNTS}
     ref = outs[SHARDED_DEVICE_COUNTS[0]]
     for d, o in outs.items():
         if o["spins_sha256"] != ref["spins_sha256"]:
@@ -393,9 +366,6 @@ def _sharded_section(rows, records):
         )
 
 
-_PLACEMENT_MARK = "PLACEMENT_RESULT "
-
-
 def _placement_jobs():
     """PT-heavy mix: R=2 ladders interleaved with mixed-budget anneals.
 
@@ -417,20 +387,11 @@ def _placement_jobs():
     return jobs
 
 
-def _placement_worker(mode: str) -> None:
-    """Child-process body: serve the PT-heavy mix at D=4 under one
-    placement mode and print one tagged JSON result line (same forced
-    host-device subprocess dance as ``_sharded_worker``)."""
-    import jax
-
+def _serve_placement(mode: str) -> dict:
+    """Serve the PT-heavy mix at D=4 under one placement mode."""
     from repro.launch.mesh import make_slot_mesh
 
     d = PLACEMENT_DEVICES
-    if len(jax.devices()) < d:
-        raise SystemExit(
-            f"placement worker: need {d} devices, see {len(jax.devices())} "
-            "(XLA_FLAGS not applied?)"
-        )
     m = ising.random_layered_model(n=MODEL_N, L=SHARDED_MODEL_L, seed=0, beta=1.0)
     slots = PLACEMENT_SLOTS_PER_DEVICE * d
     srv = SampleServer(
@@ -476,32 +437,7 @@ def _placement_worker(mode: str) -> None:
         # Counters and the hash are deterministic; best-of de-noises wall.
         if best is None or dt < best["wall_s"]:
             best = out
-    print(_PLACEMENT_MARK + json.dumps(best))
-
-
-def _spawn_placement_worker(mode: str) -> dict:
-    env = dict(os.environ)
-    flags = [f for f in env.get("XLA_FLAGS", "").split()
-             if "xla_force_host_platform_device_count" not in f]
-    flags.append(
-        f"--xla_force_host_platform_device_count={PLACEMENT_DEVICES}")
-    env["XLA_FLAGS"] = " ".join(flags)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.serve_bench", "--placement-worker",
-         mode],
-        env=env, cwd=REPO_ROOT, capture_output=True, text=True,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"placement worker mode={mode} failed "
-            f"(rc={proc.returncode}):\n{proc.stdout}\n{proc.stderr}"
-        )
-    lines = [ln for ln in proc.stdout.splitlines()
-             if ln.startswith(_PLACEMENT_MARK)]
-    if not lines:
-        raise RuntimeError(
-            f"placement worker mode={mode}: no result line\n{proc.stdout}")
-    return json.loads(lines[-1][len(_PLACEMENT_MARK):])
+    return best
 
 
 def _placement_section(rows, records):
@@ -515,8 +451,9 @@ def _placement_section(rows, records):
     swaps) is deterministic: 0.0 as long as the rebalancer keeps every
     R=2 ladder device-local.
     """
-    outs = {mode: _spawn_placement_worker(mode)
-            for mode in ("affine", "flat")}
+    if not _have_devices(PLACEMENT_DEVICES, "serve_placement_D4", rows):
+        return
+    outs = {mode: _serve_placement(mode) for mode in ("affine", "flat")}
     a, f = outs["affine"], outs["flat"]
     if a["spins_sha256"] != f["spins_sha256"]:
         raise AssertionError(
@@ -558,7 +495,6 @@ def _placement_section(rows, records):
     )
 
 
-_HETERO_MARK = "HETERO_RESULT "
 # Heterogeneous mesh section: D=4 forced devices with an UNEVEN capacity
 # vector (one big host-like device, one medium, two small) over the same
 # 8 global slots as the single-device reference.  The mix includes a
@@ -582,25 +518,17 @@ def _hetero_jobs():
     return jobs
 
 
-def _hetero_worker(layout: str) -> None:
-    """Child-process body: serve the hetero mix under one layout
-    ("hetero" = D=4 mesh with capacities [4,2,1,1]; "d1" = single
-    device, same 8 global slots) and print one tagged JSON line."""
-    import jax
-
+def _serve_hetero(layout: str) -> dict:
+    """Serve the hetero mix under one layout ("hetero" = D=4 mesh with
+    capacities [4,2,1,1]; "d1" = single device, same 8 global slots)."""
     from repro.launch.mesh import make_slot_mesh
 
     m = ising.random_layered_model(n=MODEL_N, L=SHARDED_MODEL_L, seed=0,
                                    beta=1.0)
     kw = {}
     if layout == "hetero":
-        d = len(HETERO_CAPACITIES)
-        if len(jax.devices()) < d:
-            raise SystemExit(
-                f"hetero worker: need {d} devices, see {len(jax.devices())} "
-                "(XLA_FLAGS not applied?)"
-            )
-        kw = dict(mesh=make_slot_mesh(d), capacities=HETERO_CAPACITIES)
+        kw = dict(mesh=make_slot_mesh(len(HETERO_CAPACITIES)),
+                  capacities=HETERO_CAPACITIES)
     srv = SampleServer(
         m, slots=sum(HETERO_CAPACITIES), chunk_sweeps=CHUNK, backend="jnp",
         V=V, rung="cb", telemetry=False, policy="backfill", **kw,
@@ -639,32 +567,7 @@ def _hetero_worker(layout: str) -> None:
         }
         if best is None or dt < best["wall_s"]:
             best = out
-    print(_HETERO_MARK + json.dumps(best))
-
-
-def _spawn_hetero_worker(layout: str) -> dict:
-    env = dict(os.environ)
-    flags = [f for f in env.get("XLA_FLAGS", "").split()
-             if "xla_force_host_platform_device_count" not in f]
-    d = len(HETERO_CAPACITIES) if layout == "hetero" else 1
-    flags.append(f"--xla_force_host_platform_device_count={d}")
-    env["XLA_FLAGS"] = " ".join(flags)
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.serve_bench", "--hetero-worker",
-         layout],
-        env=env, cwd=REPO_ROOT, capture_output=True, text=True,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"hetero worker layout={layout} failed "
-            f"(rc={proc.returncode}):\n{proc.stdout}\n{proc.stderr}"
-        )
-    lines = [ln for ln in proc.stdout.splitlines()
-             if ln.startswith(_HETERO_MARK)]
-    if not lines:
-        raise RuntimeError(
-            f"hetero worker layout={layout}: no result line\n{proc.stdout}")
-    return json.loads(lines[-1][len(_HETERO_MARK):])
+    return best
 
 
 def _hetero_mesh_section(rows, records):
@@ -678,8 +581,10 @@ def _hetero_mesh_section(rows, records):
     ``jobs_per_sweep_vs_D1`` is deterministically 1.0 — any dip means
     the ragged layout perturbed admission timing.
     """
-    het = _spawn_hetero_worker("hetero")
-    ref = _spawn_hetero_worker("d1")
+    if not _have_devices(len(HETERO_CAPACITIES), "serve_hetero_mesh", rows):
+        return
+    het = _serve_hetero("hetero")
+    ref = _serve_hetero("d1")
     if het["spins_sha256"] != ref["spins_sha256"]:
         raise AssertionError(
             "hetero-mesh acceptance: [4,2,1,1] per-job results differ from "
@@ -1135,8 +1040,8 @@ def run():
     )
     _sched_section(m_sched, rows, records)
 
-    # Mesh-sharded slot pool at D in {1,2,4} forced host devices, one
-    # subprocess per D (hash-parity + sweep-clock scaling asserted inside).
+    # Mesh-sharded slot pool at D in {1,2,4} devices, in this process
+    # (hash-parity + sweep-clock scaling asserted inside).
     _sharded_section(rows, records)
 
     # Placement: device-affine vs flat free list on a PT-heavy mix at
@@ -1155,12 +1060,5 @@ def run():
 
 
 if __name__ == "__main__":
-    if len(sys.argv) > 2 and sys.argv[1] == "--sharded-worker":
-        _sharded_worker(int(sys.argv[2]))
-    elif len(sys.argv) > 2 and sys.argv[1] == "--placement-worker":
-        _placement_worker(sys.argv[2])
-    elif len(sys.argv) > 2 and sys.argv[1] == "--hetero-worker":
-        _hetero_worker(sys.argv[2])
-    else:
-        for r in run():
-            print(",".join(str(x) for x in r))
+    for r in run():
+        print(",".join(str(x) for x in r))

@@ -98,7 +98,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import ising, metropolis, mt19937 as mt, reorder
-from repro.sharding.ctx import shard_map
 
 f32 = jnp.float32
 
@@ -713,9 +712,9 @@ class SweepEngine:
         specs, mesh = self._carry_pspecs(), self.mesh
 
         def run(carry: SweepCarry, num_sweeps: int) -> SweepCarry:
-            f = shard_map(
-                lambda c: body(c, num_sweeps), mesh,
-                in_specs=(specs,), out_specs=specs,
+            f = jax.shard_map(
+                lambda c: body(c, num_sweeps), mesh=mesh,
+                in_specs=(specs,), out_specs=specs, check_vma=False,
             )
             return f(carry)
 
@@ -727,9 +726,9 @@ class SweepEngine:
         )
 
         def run(carry: SweepCarry, tabs: dict, num_sweeps: int) -> SweepCarry:
-            f = shard_map(
-                lambda c, tb: body(c, tb, num_sweeps), mesh,
-                in_specs=(specs, tab_specs), out_specs=specs,
+            f = jax.shard_map(
+                lambda c, tb: body(c, tb, num_sweeps), mesh=mesh,
+                in_specs=(specs, tab_specs), out_specs=specs, check_vma=False,
             )
             return f(carry, tabs)
 
@@ -818,8 +817,9 @@ class SweepEngine:
                 in_specs = (
                     (sp_spec, self._table_pspecs()) if self.multi else (sp_spec,)
                 )
-                fn = shard_map(
-                    local, self.mesh, in_specs=in_specs, out_specs=P("data")
+                fn = jax.shard_map(
+                    local, mesh=self.mesh, in_specs=in_specs,
+                    out_specs=P("data"), check_vma=False,
                 )
             self._energies_jit = jax.jit(fn)
         if self.multi:

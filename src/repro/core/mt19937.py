@@ -94,6 +94,21 @@ def mt_twist(state: jax.Array) -> jax.Array:
     return jnp.concatenate([p1, p2, p3, last], axis=0)
 
 
+def mt_twist_inplace(ref, cols=slice(None)) -> None:
+    """`mt_twist` in place on a (624, V) Pallas ref, over columns ``cols``.
+
+    The same three chunks plus the last element.  Each chunk reads the
+    entries an earlier chunk already rewrote (``new[0:227]``, ...) back
+    from the ref, which is exactly what the value formulation passes
+    along, so the result is bit-identical to `mt_twist`.
+    """
+    T = _twist_chunk
+    ref[0:227, cols] = T(ref[0:227, cols], ref[1:228, cols], ref[397:624, cols])
+    ref[227:454, cols] = T(ref[227:454, cols], ref[228:455, cols], ref[0:227, cols])
+    ref[454:623, cols] = T(ref[454:623, cols], ref[455:624, cols], ref[227:396, cols])
+    ref[623:624, cols] = T(ref[623:624, cols], ref[0:1, cols], ref[396:397, cols])
+
+
 def mt_temper(y: jax.Array) -> jax.Array:
     """MT19937 output tempering (pure elementwise vector ops)."""
     y = y ^ (y >> np.uint32(11))
@@ -117,9 +132,12 @@ def uniforms_from_u32(u32: jax.Array) -> jax.Array:
     """Map uint32 randoms to float32 uniforms in [0, 1).
 
     Uses the 24 high bits (exactly representable in float32), the standard
-    choice for Metropolis accept tests.
+    choice for Metropolis accept tests.  The shifted value is below 2^24,
+    so it converts through int32 exactly; the TPU compiler has no
+    uint32 -> float32 conversion.
     """
-    return (u32 >> np.uint32(8)).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    top24 = (u32 >> np.uint32(8)).astype(jnp.int32)
+    return top24.astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
 
 
 def mt_uniform_blocks(state: jax.Array, num_blocks: int):

@@ -120,19 +120,37 @@ def lane_energy(
     tau_J: jax.Array,  # (n,)
     n: int,
 ) -> jax.Array:
-    """Energy of one lane-layout replica (fully vectorized, no loop over rows)."""
+    """Energy of one lane-layout replica (fully vectorized, no loop over rows).
+
+    Each spin's terms are summed elementwise, then the spins' sums are
+    added in one fixed pairwise order (`_pairwise_sum`).  A plain
+    ``jnp.sum`` leaves the order to the compiler, and on a TPU it changes
+    with the batch this function is vmapped over, so a ladder's swap
+    decisions would depend on how many slots share a device.
+    """
     rows, V = spins.shape
     lpv = rows // n
     s = spins.reshape(lpv, n, V)
-    e = -jnp.sum(h[None, :, None] * s)
+    t = h[None, :, None] * s
     # Space terms: each undirected edge counted twice -> halve.
     for d in range(base_nbr.shape[1]):
-        e -= 0.5 * jnp.sum(base_J[None, :, d, None] * s * s[:, base_nbr[:, d], :])
+        t = t + 0.5 * base_J[None, :, d, None] * s * s[:, base_nbr[:, d], :]
     # Tau terms: neighbour is next row-block; on the last block the next
     # layer is the first block of lane v+1 (global wrap lane V-1 -> 0).
     up = jnp.concatenate([s[1:], jnp.roll(s[:1], -1, axis=-1)], axis=0)
-    e -= jnp.sum(tau_J[None, :, None] * s * up)
-    return e
+    t = t + tau_J[None, :, None] * s * up
+    return -_pairwise_sum(t)
+
+
+def _pairwise_sum(x: jax.Array) -> jax.Array:
+    """Sum of all elements as a balanced tree of elementwise adds: zero
+    padded to a power of two, then halved until one element is left."""
+    x = jnp.ravel(x)
+    size = 1 << max(x.size - 1, 0).bit_length()
+    x = jnp.pad(x, (0, size - x.size))
+    while x.size > 1:
+        x = x[: x.size // 2] + x[x.size // 2 :]
+    return x[0]
 
 
 def draw_swap_uniforms(swap_rng: jax.Array, num_replicas: int):

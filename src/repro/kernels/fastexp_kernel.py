@@ -54,7 +54,8 @@ def _accurate_body(x_ref, o_ref):
 def fastexp_2d(
     x: jax.Array,
     flavor: str = "fast",
-    interpret: bool = True,
+    *,
+    interpret: bool,
     block_rows: int = BLOCK_ROWS,
 ) -> jax.Array:
     """Apply the approximation to a (rows, 128*k) float32 array via Pallas."""

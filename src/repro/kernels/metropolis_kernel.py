@@ -3,54 +3,55 @@
 TPU adaptation of the paper's A.4/B.2 rungs: the model's L layers are
 interlaced across the 128 TPU lanes (reorder.py), so one VPU op advances 128
 spins — the CPU version's 4-wide SSE and the GPU version's 32-thread
-coalesced warp both map to the lane dimension here.  Per grid step, one
-replica's full state lives in VMEM:
+coalesced warp both map to the lane dimension here.  The grid runs over
+TILES of ``bt`` replicas; one tile's whole state lives in VMEM:
 
     spins/h_space/h_tau: 3 x rows x 128 x 4 B   (rows = L/128 * n)
     MT19937 state:       624 x 128 x 4 B = 312 KiB
+    staged uniforms:     rows x 128 x 4 B       (scratch)
 
-e.g. the paper's production shape (256 layers x 96 spins, rows=192) uses
-~700 KiB of VMEM — far under the ~16 MiB budget, leaving room to raise the
-replica count per core via the batch grid.
+per replica.  With the blocks double-buffered, the paper's production
+shape (256 layers x 96 spins, rows=192) takes about 2.5 MiB per replica;
+`pick_replica_tile` sizes ``bt`` to a VMEM budget so every slot count
+compiles.
 
-Two sweep orders are implemented, both fused with in-kernel MT19937:
+Both sweep orders share one layout and one RNG stage:
 
-* ``metropolis_multisweep_kernel`` — the sequential-order A.4 rung: each
-  grid step owns its replica tile's (624, bt*128) interlaced MT19937 state
-  block, regenerates the sweep's uniforms in-register (twist -> temper ->
-  24-bit floats, exactly `core/mt19937.py`'s blocked formulation), and
-  advances ``num_sweeps`` full sweeps in a `lax.fori_loop` — one
-  `pallas_call` advances ``num_sweeps x B`` replica-sweeps with zero host
-  round-trips.  The row loop is sequential (the paper vectorizes *within*
-  a visit, not across visits); neighbour/coupling tables are pre-gathered
-  per ROW (`_row_tables`) so each row step is one direct dynamic load —
-  no modulo/base index arithmetic and no per-row gather from the (n, SD)
-  site tables in the hot loop.
-* ``make_colored_multisweep_kernel`` — the graph-colored "cb" rung: the
-  row loop is replaced by C whole-lattice masked vector updates (one per
-  conflict-free color class, `reorder.colored_classes`).  The body vmaps
-  the SAME per-replica functions the jnp backend uses
-  (`metropolis.colored_flip_spins` / `metropolis.lane_h_eff`), so the two
-  backends are bit-identical by construction: same uniforms, same class
-  visit order, same elementwise ops.  Effective fields are recomputed by
-  dense gathers once per launch (they are a pure function of the final
-  spins) instead of scatter-adds, which is what keeps every float
-  reproducible.
+* Row values are ``(bt, 128)``: ``ref[:, q, :]`` takes row ``q`` of every
+  replica of the tile, so each VPU op advances the tile in lockstep.
+* Each sweep first regenerates its uniforms in VMEM (`_draw_uniforms`):
+  per replica, ``blocks = ceil(rows/624)`` in-place twists of the tile's
+  (624, bt*128) interlaced MT19937 block (`mt19937.mt_twist_inplace`),
+  temper, 24-bit floats, tail discarded — the host path's draw pattern
+  (`mt.mt_uniforms_count`), so the two backends consume identical streams.
+* Neighbour sites come from SMEM (the base graph's ``(n*SD,)`` table);
+  couplings are one packed per-site row, read from SMEM as scalars when
+  the tile shares one model, or as per-slot ``(bt, 1)`` columns of a VMEM
+  ``(bt, n, K)`` block when each slot sweeps its own model (multi-tenant).
+  With ``bt`` copies of one table the floats are identical either way.
 
-The per-sweep uniform stream is bit-identical to the host path for both
-orders: each draws ceil(rows/624) fresh 624-row blocks per sweep and
-discards the tail, so jnp-backend and Pallas-backend engines produce
-bit-exact spins (tests/test_engine.py, tests/test_colored.py).
+The two orders:
 
-Validation is via ``interpret=True`` on CPU against the pure-jnp oracles
-in ``ref.py``; the colored body's vmap-over-tile formulation targets the
-interpret/Mosaic-jnp path (a hand-scheduled non-interpret TPU build would
-specialize the gathers).
+* ``metropolis_multisweep_kernel`` — the sequential-order A.4 rung: rows
+  are visited in order, each visit flips the row and adds its
+  contribution to the neighbour rows' carried fields (lane rotation at the
+  section-boundary rows), exactly `metropolis.sweep_lane`'s op order.
+* ``make_colored_multisweep_kernel`` — the graph-colored "cb" rung: rows
+  are visited class by class (`reorder.colored_classes`); each visit
+  recomputes the row's fields from the current spins and flips it.  Rows
+  of one class never interact, so flipping them one after another reads
+  the same values as `metropolis.colored_flip_spins`' whole-class gather
+  and the result is bit-identical to the jnp backend.  Fields are
+  recomputed once per launch from the final spins (`metropolis.lane_h_eff`
+  row by row).
+
+``interpret`` has no default at any entry: `kernels.ops` is the one place
+that chooses it (compiled on a TPU, the interpreter elsewhere).
 
 ``metropolis_sweep_kernel`` (single sweep, host-generated uniforms) is
-DEPRECATED — it survives one release as a thin shim over the shared fused
-body at ``num_sweeps=1`` for the launch-structure benchmark and the
-historical oracle tests.
+DEPRECATED — it survives one release as a shim over the A.4 body at
+``num_sweeps=1`` for the launch-structure benchmark and the historical
+oracle tests.
 """
 
 from __future__ import annotations
@@ -59,224 +60,232 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import fastexp as fx
-from repro.core import metropolis as mp
 from repro.core import mt19937 as mt
 
 LANES = 128
 f32 = jnp.float32
 
-
-def _row_tables(base_nbr, base_J2, tau_J2, rows: int, n: int):
-    """Pre-gather the per-site tables into per-ROW tables.
-
-    ``row_nbr[q, d]`` is the ABSOLUTE neighbour row of row ``q`` (the
-    per-row ``base = q - q % n`` offset is folded in ahead of time), and
-    ``row_j2``/``row_tau2`` are the couplings tiled over the layer blocks
-    — so the kernel's row loop does one direct dynamic load per table
-    instead of a modulo, an offset add, and a gather from the (n, SD)
-    site tables.
-    """
-    lpv = rows // n
-    row_nbr = (
-        jnp.arange(lpv, dtype=jnp.int32)[:, None, None] * n + base_nbr[None]
-    ).reshape(rows, base_nbr.shape[1])
-    row_j2 = jnp.tile(base_J2, (lpv, 1))
-    row_tau2 = jnp.tile(tau_J2.reshape(-1, 1), (lpv, 1))
-    return row_nbr, row_j2, row_tau2
+#: VMEM a replica tile may fill (v5e has 128 MiB per core).
+TILE_VMEM_BUDGET = 48 << 20
+#: Mosaic's default scoped-VMEM limit on v5e; a tile that needs more (with
+#: headroom for compiler temporaries) raises the limit to what it needs.
+DEFAULT_SCOPED_VMEM = 16 << 20
+VMEM_HEADROOM = 8 << 20
 
 
-def _draw_sweep_uniforms(s_rng, blocks: int, rows: int):
-    """One sweep's worth of in-register uniforms from interlaced MT19937
-    state: ``blocks = ceil(rows/624)`` fresh twist/temper blocks, tail rows
-    discarded — THE draw pattern (`mt.mt_uniforms_count`) that keeps the
-    in-kernel stream bit-identical to the host backend.  Returns
-    ``(new_state, u)`` with u of shape (rows, lanes-of-state)."""
-    outs = []
-    for _ in range(blocks):  # static unroll, blocks is tiny
-        s_rng = mt.mt_twist(s_rng)
-        outs.append(mt.mt_temper(s_rng))
-    u32 = outs[0] if blocks == 1 else jnp.concatenate(outs, axis=0)
-    return s_rng, mt.uniforms_from_u32(u32)[:rows]
+def _vmem_bytes(sublanes: int, lanes: int) -> int:
+    """f32 bytes of a (sublanes, lanes) block padded to (8, 128) tiles."""
+    return -(-sublanes // 8) * 8 * (-(-lanes // LANES)) * LANES * 4
 
 
-def _row_sweep(
-    o_spins_ref,  # (bt, rows, 128) — updated in place
-    o_hs_ref,
-    o_ht_ref,
-    u,  # (rows, bt*128) f32 VALUE (not a ref) — uniforms for this sweep
-    row_nbr_ref,  # (rows, SD) int32 absolute neighbour rows (_row_tables)
-    row_j2_ref,  # (rows, SD) f32 (pre-doubled); (bt, rows, SD) if multi
-    row_tau2_ref,  # (rows, 1) f32 (pre-doubled); (bt, rows, 1) if multi
-    beta,  # (bt, 1, 1) f32
+def replica_vmem_bytes(rows: int, n: int, k: int, *, fields_in: bool, multi: bool) -> int:
+    """VMEM one replica adds to a tile: its double-buffered input and
+    output blocks plus its staged uniforms.  ``fields_in`` (A.4) ships the
+    carried fields in; ``multi`` adds the per-slot (n, k) coupling block."""
+    field = _vmem_bytes(rows, LANES)
+    gen = _vmem_bytes(mt.N, LANES)
+    ins = field * (3 if fields_in else 1) + gen + _vmem_bytes(1, LANES)
+    if multi:
+        ins += _vmem_bytes(n, k)
+    outs = 3 * field + gen
+    return 2 * (ins + outs) + field
+
+
+def pick_replica_tile(batch: int, per_replica: int) -> int:
+    """Largest divisor of ``batch`` whose tile fits `TILE_VMEM_BUDGET`."""
+    fits = [
+        d for d in range(1, batch + 1)
+        if batch % d == 0 and d * per_replica <= TILE_VMEM_BUDGET
+    ]
+    return max(fits, default=1)
+
+
+def _tile(batch: int, replica_tile: int | None, per_replica: int) -> int:
+    if replica_tile is None:
+        return pick_replica_tile(batch, per_replica)
+    if batch % replica_tile != 0:
+        raise ValueError(f"replica_tile {replica_tile} must divide batch {batch}")
+    return replica_tile
+
+
+def _compiler_params(bt: int, per_replica: int):
+    need = bt * per_replica + VMEM_HEADROOM
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",),
+        vmem_limit_bytes=need if need > DEFAULT_SCOPED_VMEM else None,
+    )
+
+
+def _coupling_reader(cpl_ref, k: int, multi: bool):
+    """``coef(i, c)``: coupling column ``c`` of site ``i`` — a per-slot
+    ``(bt, 1)`` column (multi-tenant VMEM block) or one SMEM scalar."""
+    if multi:
+        return lambda i, c: cpl_ref[:, i, pl.ds(c, 1)]
+    return lambda i, c: cpl_ref[i * k + c]
+
+
+def _draw_uniforms(rng_ref, u_ref, bt: int, rows: int, blocks: int) -> None:
+    """Stage one sweep's uniforms: ``u_ref[b, q]`` is row ``q`` of replica
+    ``b``, drawn from its 128 generator columns of ``rng_ref`` as
+    ``blocks = ceil(rows/624)`` fresh blocks with the tail discarded."""
+
+    def replica(b, carry):
+        cols = pl.ds(pl.multiple_of(b * LANES, LANES), LANES)
+        for k in range(blocks):  # static, blocks is tiny
+            mt.mt_twist_inplace(rng_ref, cols)
+            m = min(mt.N, rows - k * mt.N)
+            u_ref[b, k * mt.N : k * mt.N + m, :] = mt.uniforms_from_u32(
+                mt.mt_temper(rng_ref[0:m, cols])
+            )
+        return carry
+
+    lax.fori_loop(0, bt, replica, 0)
+
+
+def _a4_body(
     n: int,
     sd: int,
     rows: int,
     bt: int,
-    exp_fn,
-    multi: bool = False,
-):
-    """One full sequential-order sweep over a tile of ``bt`` replicas.
-
-    Shared by the fused multi-sweep kernel and the deprecated single-sweep
-    shim, so the flip/neighbour-update math exists exactly once.  Replica
-    b of the tile owns uniform columns b*128..(b+1)*128.  All tables are
-    per-row-gathered, so each step's index arithmetic is a single dynamic
-    row load (first/last layer blocks still special-case the lane-rotated
-    tau wrap, where the target row is an affine function of q).
-
-    ``multi=True`` is the multi-tenant flavour: the coupling tables carry
-    a leading replica-tile dim (each slot sweeps its own model), so the
-    j2/tau2 loads become per-replica ``(bt, 1, ·)`` values that broadcast
-    against ``smul`` exactly like the shared scalars do — with ``bt``
-    copies of one table the floats are bit-identical to the shared path.
-    The neighbour ROW table stays shared: multi-tenant slots share one
-    lattice topology (engine.check_same_topology).
-    """
-
-    def rmw(ref, row, contrib):
-        idx = (slice(None), pl.ds(row, 1), slice(None))
-        pl.store(ref, idx, pl.load(ref, idx) + contrib)
-
-    def row_step(q, wrap):
-        idx = (slice(None), pl.ds(q, 1), slice(None))
-        s = pl.load(o_spins_ref, idx)  # (bt, 1, 128)
-        hsum = pl.load(o_hs_ref, idx) + pl.load(o_ht_ref, idx)
-        uq = lax.dynamic_slice_in_dim(u, q, 1, axis=0)  # (1, bt*128)
-        uq = uq.reshape(bt, 1, LANES)
-        x = (f32(-2.0) * beta) * s * hsum
-        p = exp_fn(x)
-        mask = (uq < p).astype(f32)  # Figure 10: branch-free vector select
-        smul = s * mask
-        pl.store(o_spins_ref, idx, s * (f32(1.0) - f32(2.0) * mask))
-        nbr_row = pl.load(row_nbr_ref, (pl.ds(q, 1), slice(None)))  # (1, SD)
-        if multi:
-            j2_row = pl.load(row_j2_ref, (slice(None), pl.ds(q, 1), slice(None)))
-            for d in range(sd):  # static unroll over the sparse degree
-                rmw(o_hs_ref, nbr_row[0, d], -smul * j2_row[:, :, d : d + 1])
-            tc = -smul * pl.load(
-                row_tau2_ref, (slice(None), pl.ds(q, 1), slice(None))
-            )  # (bt, 1, 1) per-replica tau coupling
-        else:
-            j2_row = pl.load(row_j2_ref, (pl.ds(q, 1), slice(None)))
-            for d in range(sd):  # static unroll over the sparse degree
-                rmw(o_hs_ref, nbr_row[0, d], -smul * j2_row[0, d])
-            tc = -smul * pl.load(row_tau2_ref, (pl.ds(q, 1), slice(None)))[0, 0]
-        if wrap == -1:  # first layer block (q in [0, n)): down-link wraps
-            rmw(o_ht_ref, rows - n + q, jnp.roll(tc, -1, axis=2))
-            rmw(o_ht_ref, q + n, tc)
-        elif wrap == +1:  # last layer block (q in [rows-n, rows)): up wraps
-            rmw(o_ht_ref, q - n, tc)
-            rmw(o_ht_ref, q - (rows - n), jnp.roll(tc, 1, axis=2))
-        else:
-            rmw(o_ht_ref, q - n, tc)
-            rmw(o_ht_ref, q + n, tc)
-
-    lax.fori_loop(0, n, lambda q, _: (row_step(q, -1), 0)[1], 0)
-    lax.fori_loop(n, rows - n, lambda q, _: (row_step(q, 0), 0)[1], 0)
-    lax.fori_loop(rows - n, rows, lambda q, _: (row_step(q, +1), 0)[1], 0)
-
-
-def _make_fused_body(
-    n: int,
-    sd: int,
-    rows: int,
-    bt: int,
-    blocks: int,
     num_sweeps: int,
     exp_flavor: str,
-    host_uniforms: bool = False,
-    multi: bool = False,
+    multi: bool,
+    host_uniforms: bool,
 ):
-    """Sequential-order sweep body over a TILE of ``bt`` replicas.
+    """Sequential-order sweep body over a tile of ``bt`` replicas.
 
-    The default flavour fuses the RNG: the tile owns its (624, bt*128)
-    interlaced MT19937 block and draws ``blocks = ceil(rows/624)`` fresh
-    generator blocks per sweep, tail discarded — the exact draw pattern of
-    the host path (`engine._build_jnp`), which is what makes the two
-    backends bit-exact.  This is the paper's batching insight applied
-    twice: layers fill the 128 lanes, and replicas fill an extra leading
-    vector dimension.
-
-    ``host_uniforms=True`` is the DEPRECATED single-sweep flavour (uniforms
-    arrive as an input ref, ``num_sweeps`` must be 1) kept for the
-    launch-structure benchmark; it shares `_row_sweep` so no sweep math is
-    duplicated.  ``multi=True`` threads per-replica coupling tables (the
-    j2/tau2 refs gain a leading tile dim — see `_row_sweep`).
+    ``host_uniforms=True`` is the DEPRECATED single-sweep flavour: the
+    uniforms arrive as an input block instead of being drawn in-kernel.
     """
     exp_fn = fx.EXP_FNS[exp_flavor]
+    k = sd + 1  # per-site couplings: doubled J_0..J_{sd-1}, doubled tau J
+    blocks = -(-rows // mt.N)
 
-    if host_uniforms:
-        assert num_sweeps == 1, "host-uniform flavour is single-sweep only"
-        assert not multi, "host-uniform flavour has no multi-tenant variant"
+    def body(nbr_ref, cpl_ref, beta_ref, spins_ref, hs_ref, ht_ref, src_ref, *outs):
+        if host_uniforms:
+            o_spins, o_hs, o_ht = outs
+            u_ref = src_ref
+        else:
+            o_spins, o_hs, o_ht, o_rng, u_ref = outs
+            o_rng[...] = src_ref[...]
+        o_spins[...] = spins_ref[...]
+        o_hs[...] = hs_ref[...]
+        o_ht[...] = ht_ref[...]
+        beta = beta_ref[:, 0, :]  # (bt, 128)
+        coef = _coupling_reader(cpl_ref, k, multi)
 
-        def u_body(
-            spins_ref,  # (bt, rows, 128)
-            hs_ref,
-            ht_ref,
-            u_ref,  # (bt, rows, 128) host-generated uniforms
-            row_nbr_ref,
-            row_j2_ref,
-            row_tau2_ref,
-            beta_ref,  # (bt, 1) f32
-            o_spins_ref,
-            o_hs_ref,
-            o_ht_ref,
-        ):
-            o_spins_ref[...] = spins_ref[...]
-            o_hs_ref[...] = hs_ref[...]
-            o_ht_ref[...] = ht_ref[...]
-            u = u_ref[...].transpose(1, 0, 2).reshape(rows, bt * LANES)
-            _row_sweep(
-                o_spins_ref, o_hs_ref, o_ht_ref, u,
-                row_nbr_ref, row_j2_ref, row_tau2_ref,
-                beta_ref[...].reshape(bt, 1, 1),
-                n, sd, rows, bt, exp_fn,
-            )
+        def rmw(ref, row, contrib):
+            ref[:, row, :] = ref[:, row, :] + contrib
 
-        return u_body
+        def row_step(q, wrap):
+            i = lax.rem(q, n)
+            s = o_spins[:, q, :]
+            x = (f32(-2.0) * beta) * s * (o_hs[:, q, :] + o_ht[:, q, :])
+            mask = (u_ref[:, q, :] < exp_fn(x)).astype(f32)  # Figure 10 select
+            smul = s * mask
+            o_spins[:, q, :] = s * (f32(1.0) - f32(2.0) * mask)
+            base = q - i
+            for d in range(sd):  # static unroll over the sparse degree
+                rmw(o_hs, base + nbr_ref[i * sd + d], -smul * coef(i, d))
+            tc = -smul * coef(i, sd)
+            if wrap == -1:  # first layer block: the down-link wraps a lane
+                rmw(o_ht, rows - n + i, pltpu.roll(tc, LANES - 1, 1))
+                rmw(o_ht, q + n, tc)
+            elif wrap == +1:  # last layer block: the up-link wraps a lane
+                rmw(o_ht, q - n, tc)
+                rmw(o_ht, i, pltpu.roll(tc, 1, 1))
+            else:
+                rmw(o_ht, q - n, tc)
+                rmw(o_ht, q + n, tc)
 
-    def body(
-        spins_ref,  # (bt, rows, 128)
-        hs_ref,
-        ht_ref,
-        rng_ref,  # (624, bt*128) uint32 — the tile's interlaced MT19937
-        row_nbr_ref,  # (rows, SD) int32 absolute rows
-        row_j2_ref,  # (rows, SD) f32 (pre-doubled)
-        row_tau2_ref,  # (rows, 1) f32 (pre-doubled)
-        beta_ref,  # (bt, 1) f32
-        o_spins_ref,
-        o_hs_ref,
-        o_ht_ref,
-        o_rng_ref,
-    ):
-        o_spins_ref[...] = spins_ref[...]
-        o_hs_ref[...] = hs_ref[...]
-        o_ht_ref[...] = ht_ref[...]
-        o_rng_ref[...] = rng_ref[...]
-        beta = beta_ref[...].reshape(bt, 1, 1)
+        def rows_loop(lo, hi, wrap):
+            lax.fori_loop(lo, hi, lambda q, c: (row_step(q, wrap), c)[1], 0)
 
-        def sweep_step(_k, carry):
-            s_rng, u = _draw_sweep_uniforms(o_rng_ref[...], blocks, rows)
-            o_rng_ref[...] = s_rng
-            _row_sweep(
-                o_spins_ref, o_hs_ref, o_ht_ref, u,
-                row_nbr_ref, row_j2_ref, row_tau2_ref,
-                beta, n, sd, rows, bt, exp_fn, multi=multi,
-            )
+        def sweep(_k, carry):
+            if not host_uniforms:
+                _draw_uniforms(o_rng, u_ref, bt, rows, blocks)
+            rows_loop(0, n, -1)
+            rows_loop(n, rows - n, 0)
+            rows_loop(rows - n, rows, +1)
             return carry
 
-        lax.fori_loop(0, num_sweeps, sweep_step, 0)
+        lax.fori_loop(0, num_sweeps, sweep, 0)
 
     return body
 
 
-@functools.partial(
-    jax.jit, static_argnames=("n", "exp_flavor", "interpret")
-)
+def _beta_lanes(beta: jax.Array, batch: int) -> jax.Array:
+    """(B,) betas as a (B, 1, 128) lane-dense block: a (bt, 1, 128) tile
+    satisfies the TPU tiling rule for every replica tile size."""
+    return jnp.broadcast_to(
+        jnp.reshape(beta, (batch, 1, 1)).astype(f32), (batch, 1, LANES)
+    )
+
+
+def _a4_call(
+    spins, h_space, h_tau, src, base_nbr, cpl, beta,
+    *, n, num_sweeps, exp_flavor, interpret, replica_tile, multi, host_uniforms,
+):
+    """The one launch configuration of every A.4 entry.  ``src`` is the
+    (624, B*128) generator state, or (host_uniforms) the (B, rows, 128)
+    uniforms; ``cpl`` is the packed coupling table (`_coupling_reader`)."""
+    B, rows, lanes = spins.shape
+    assert lanes == LANES, spins.shape
+    sd = base_nbr.shape[-1]
+    k = sd + 1
+    per = replica_vmem_bytes(rows, n, k, fields_in=True, multi=multi)
+    bt = _tile(B, replica_tile, per)
+    body = _a4_body(n, sd, rows, bt, num_sweeps, exp_flavor, multi, host_uniforms)
+    tile = pl.BlockSpec((bt, rows, LANES), lambda g: (g, 0, 0))
+    gen = pl.BlockSpec((mt.N, bt * LANES), lambda g: (0, g))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    field = jax.ShapeDtypeStruct((B, rows, LANES), f32)
+    if host_uniforms:
+        src_spec, out_shape, out_specs, scratch = tile, (field,) * 3, (tile,) * 3, []
+    else:
+        assert src.shape == (mt.N, B * LANES), (src.shape, B)
+        src_spec = gen
+        out_shape = (field,) * 3 + (jax.ShapeDtypeStruct(src.shape, jnp.uint32),)
+        out_specs = (tile,) * 3 + (gen,)
+        scratch = [pltpu.VMEM((bt, rows, LANES), f32)]
+    return pl.pallas_call(
+        body,
+        out_shape=out_shape,
+        grid=(B // bt,),
+        in_specs=[
+            smem,
+            pl.BlockSpec((bt, n, k), lambda g: (g, 0, 0)) if multi else smem,
+            pl.BlockSpec((bt, 1, LANES), lambda g: (g, 0, 0)),
+            tile,
+            tile,
+            tile,
+            src_spec,
+        ],
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(bt, per),
+        interpret=interpret,
+    )(
+        jnp.reshape(base_nbr, (-1,)).astype(jnp.int32), cpl,
+        _beta_lanes(beta, B), spins, h_space, h_tau, src,
+    )
+
+
+def _shared_a4_couplings(base_J2, tau_J2) -> jax.Array:
+    """One model's doubled couplings packed per site, flat for SMEM."""
+    n = base_J2.shape[0]
+    return jnp.concatenate(
+        [jnp.asarray(base_J2, f32), jnp.reshape(tau_J2, (n, 1)).astype(f32)], axis=1
+    ).reshape(-1)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "exp_flavor", "interpret"))
 def metropolis_sweep_kernel(
     spins: jax.Array,  # (B, rows, 128) f32 in {-1,+1}
     h_space: jax.Array,  # (B, rows, 128)
@@ -284,102 +293,26 @@ def metropolis_sweep_kernel(
     u: jax.Array,  # (B, rows, 128) uniforms
     base_nbr: jax.Array,  # (n, SD) int32
     base_J2: jax.Array,  # (n, SD) f32
-    tau_J2: jax.Array,  # (n, 1) f32
-    beta: jax.Array,  # (B, 1) f32
+    tau_J2: jax.Array,  # (n,) or (n, 1) f32
+    beta: jax.Array,  # (B,) or (B, 1) f32
     n: int,
     exp_flavor: str = "fast",
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """DEPRECATED single-sweep kernel (host-generated uniforms, one launch
-    per sweep): a thin shim over the shared fused body at ``num_sweeps=1``.
-    New code should use `metropolis_multisweep_kernel` (in-kernel RNG); this
-    survives one release as the seed-architecture baseline that
+    per sweep): the A.4 body at ``num_sweeps=1``.  New code should use
+    `metropolis_multisweep_kernel` (in-kernel RNG); this survives one
+    release as the seed-architecture baseline that
     `benchmarks.kernel_bench.launch_structure_compare` measures against and
     as the entry the historical oracle tests exercise.
     """
-    B, rows, lanes = spins.shape
-    assert lanes == LANES, spins.shape
-    sd = base_nbr.shape[1]
-    row_nbr, row_j2, row_tau2 = _row_tables(base_nbr, base_J2, tau_J2, rows, n)
-    body = _make_fused_body(
-        n, sd, rows, 1, 0, 1, exp_flavor, host_uniforms=True
+    return _a4_call(
+        spins, h_space, h_tau, u, base_nbr,
+        _shared_a4_couplings(base_J2, tau_J2), beta,
+        n=n, num_sweeps=1, exp_flavor=exp_flavor, interpret=interpret,
+        replica_tile=None, multi=False, host_uniforms=True,
     )
-    rep_spec = pl.BlockSpec((1, rows, LANES), lambda b: (b, 0, 0))
-    shared2d = lambda a: pl.BlockSpec(a.shape, lambda b: (0, 0))
-    out = pl.pallas_call(
-        body,
-        out_shape=tuple(
-            jax.ShapeDtypeStruct((B, rows, LANES), jnp.float32) for _ in range(3)
-        ),
-        grid=(B,),
-        in_specs=[
-            rep_spec,
-            rep_spec,
-            rep_spec,
-            rep_spec,
-            shared2d(row_nbr),
-            shared2d(row_j2),
-            shared2d(row_tau2),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-        ],
-        out_specs=(rep_spec, rep_spec, rep_spec),
-        interpret=interpret,
-    )(spins, h_space, h_tau, u, row_nbr, row_j2, row_tau2, beta)
-    return out
-
-
-def _fused_multisweep_call(
-    spins, h_space, h_tau, rng, row_nbr, row_j2, row_tau2, beta,
-    n: int, num_sweeps: int, exp_flavor: str, interpret: bool,
-    replica_tile: int | None, multi: bool,
-):
-    """The one launch configuration both fused sequential-order entries
-    share: tiles, specs, out shapes, and the `_make_fused_body` call.
-    ``multi`` only switches the j2/tau2 operands from shared ``(rows, ·)``
-    tables to per-tile ``(bt, rows, ·)`` blocks of ``[B, rows, ·]``
-    inputs — everything else is identical by construction, so the single-
-    and multi-tenant launch paths cannot diverge."""
-    B, rows, lanes = spins.shape
-    assert lanes == LANES, spins.shape
-    assert rng.shape == (mt.N, B * LANES), (rng.shape, B)
-    bt = B if replica_tile is None else replica_tile
-    if B % bt != 0:
-        raise ValueError(f"replica_tile {bt} must divide batch {B}")
-    sd = row_nbr.shape[-1]
-    blocks = -(-rows // mt.N)  # ceil
-    body = _make_fused_body(
-        n, sd, rows, bt, blocks, num_sweeps, exp_flavor, multi=multi
-    )
-    tile_spec = pl.BlockSpec((bt, rows, LANES), lambda g: (g, 0, 0))
-    rng_spec = pl.BlockSpec((mt.N, bt * LANES), lambda g: (0, g))
-    shared2d = lambda a: pl.BlockSpec(a.shape, lambda g: (0, 0))
-    if multi:
-        j2_spec = pl.BlockSpec((bt, rows, sd), lambda g: (g, 0, 0))
-        tau2_spec = pl.BlockSpec((bt, rows, 1), lambda g: (g, 0, 0))
-    else:
-        j2_spec, tau2_spec = shared2d(row_j2), shared2d(row_tau2)
-    return pl.pallas_call(
-        body,
-        out_shape=(
-            jax.ShapeDtypeStruct((B, rows, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((B, rows, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((B, rows, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((mt.N, B * LANES), jnp.uint32),
-        ),
-        grid=(B // bt,),
-        in_specs=[
-            tile_spec,
-            tile_spec,
-            tile_spec,
-            rng_spec,
-            shared2d(row_nbr),
-            j2_spec,
-            tau2_spec,
-            pl.BlockSpec((bt, 1), lambda g: (g, 0)),
-        ],
-        out_specs=(tile_spec, tile_spec, tile_spec, rng_spec),
-        interpret=interpret,
-    )(spins, h_space, h_tau, rng, row_nbr, row_j2, row_tau2, beta)
 
 
 @functools.partial(
@@ -393,12 +326,13 @@ def metropolis_multisweep_kernel(
     rng: jax.Array,  # (624, B*128) uint32 interlaced MT19937 state
     base_nbr: jax.Array,  # (n, SD) int32
     base_J2: jax.Array,  # (n, SD) f32
-    tau_J2: jax.Array,  # (n, 1) f32
-    beta: jax.Array,  # (B, 1) f32
+    tau_J2: jax.Array,  # (n,) or (n, 1) f32
+    beta: jax.Array,  # (B,) or (B, 1) f32
     n: int,
     num_sweeps: int,
     exp_flavor: str = "fast",
-    interpret: bool = True,
+    *,
+    interpret: bool,
     replica_tile: int | None = None,
 ):
     """``num_sweeps`` fused sweeps for each of B replicas, RNG in-kernel.
@@ -406,15 +340,15 @@ def metropolis_multisweep_kernel(
     Returns ``(spins, h_space, h_tau, rng)`` — one `pallas_call`, no
     host-side uniform buffers, no per-sweep launches.  Replicas advance in
     lockstep inside the body (batched vector ops), and the grid runs over
-    replica TILES of ``replica_tile`` replicas (default: all B in one tile)
-    so the resident working set can be sized to VMEM without changing the
-    math: tiles are independent, bit-equal to the one-tile case.
+    replica TILES of ``replica_tile`` replicas (default: the largest
+    divisor of B that fits the VMEM budget); tiles are independent, so
+    every tiling is bit-equal to every other.
     """
-    rows = spins.shape[1]
-    row_nbr, row_j2, row_tau2 = _row_tables(base_nbr, base_J2, tau_J2, rows, n)
-    return _fused_multisweep_call(
-        spins, h_space, h_tau, rng, row_nbr, row_j2, row_tau2, beta,
-        n, num_sweeps, exp_flavor, interpret, replica_tile, multi=False,
+    return _a4_call(
+        spins, h_space, h_tau, rng, base_nbr,
+        _shared_a4_couplings(base_J2, tau_J2), beta,
+        n=n, num_sweeps=num_sweeps, exp_flavor=exp_flavor, interpret=interpret,
+        replica_tile=replica_tile, multi=False, host_uniforms=False,
     )
 
 
@@ -429,95 +363,140 @@ def metropolis_multisweep_multi_kernel(
     rng: jax.Array,  # (624, B*128) uint32 interlaced MT19937 state
     base_nbr: jax.Array,  # (n, SD) int32 — SHARED topology
     base_J2_b: jax.Array,  # (B, n, SD) f32 — PER-SLOT couplings
-    tau_J2_b: jax.Array,  # (B, n, 1) f32 — PER-SLOT tau couplings
-    beta: jax.Array,  # (B, 1) f32
+    tau_J2_b: jax.Array,  # (B, n) or (B, n, 1) f32 — PER-SLOT tau couplings
+    beta: jax.Array,  # (B,) or (B, 1) f32
     n: int,
     num_sweeps: int,
     exp_flavor: str = "fast",
-    interpret: bool = True,
+    *,
+    interpret: bool,
     replica_tile: int | None = None,
 ):
     """Multi-tenant flavour of `metropolis_multisweep_kernel`: the coupling
-    tables gain a leading replica dim and ride the replica grid as batched
-    kernel inputs, so one fused launch advances B slots each sweeping its
-    OWN model (same lattice topology — the neighbour table stays shared).
-    With B copies of one model's tables this is bit-identical to the
-    single-model kernel (the per-replica float ops are the same).
+    tables gain a leading replica dim and ride the replica grid as a
+    batched VMEM input, so one fused launch advances B slots each sweeping
+    its OWN model (same lattice topology — the neighbour table stays
+    shared).  With B copies of one model's tables this is bit-identical to
+    the single-model kernel (the per-replica float ops are the same).
     """
-    B, rows, lanes = spins.shape
-    assert base_J2_b.shape[0] == B and tau_J2_b.shape[0] == B
-    sd = base_nbr.shape[1]
-    lpv = rows // n
-    # Per-row tables as in `_row_tables`, tiled per slot for the coupling
-    # operands; the absolute-neighbour-row table is topology, hence shared.
-    row_nbr = (
-        jnp.arange(lpv, dtype=jnp.int32)[:, None, None] * n + base_nbr[None]
-    ).reshape(rows, sd)
-    row_j2_b = jnp.tile(base_J2_b, (1, lpv, 1))  # (B, rows, SD)
-    row_tau2_b = jnp.tile(tau_J2_b, (1, lpv, 1))  # (B, rows, 1)
-    return _fused_multisweep_call(
-        spins, h_space, h_tau, rng, row_nbr, row_j2_b, row_tau2_b, beta,
-        n, num_sweeps, exp_flavor, interpret, replica_tile, multi=True,
+    B = spins.shape[0]
+    cpl = jnp.concatenate(
+        [base_J2_b.astype(f32), jnp.reshape(tau_J2_b, (B, n, 1)).astype(f32)], axis=2
+    )
+    return _a4_call(
+        spins, h_space, h_tau, rng, base_nbr, cpl, beta,
+        n=n, num_sweeps=num_sweeps, exp_flavor=exp_flavor, interpret=interpret,
+        replica_tile=replica_tile, multi=True, host_uniforms=False,
     )
 
 
 # -----------------------------------------------------------------------------
-# Graph-colored "cb" rung: C whole-lattice vector updates per sweep.
+# Graph-colored "cb" rung: rows visited class by class, fields recomputed.
 # -----------------------------------------------------------------------------
 
 
-def _make_colored_body(
-    tables_treedef,
-    n: int,
-    rows: int,
-    bt: int,
-    blocks: int,
-    num_sweeps: int,
-    exp_flavor: str,
-):
+def _cb_body(n: int, sd: int, rows: int, bt: int, num_sweeps: int, exp_flavor: str, multi: bool):
     """Fused colored-sweep body over a tile of ``bt`` replicas.
 
-    No row loop: each sweep is C masked whole-lattice updates, computed by
-    vmapping the per-replica `metropolis.colored_flip_spins` over the tile
-    — literally the jnp backend's function, so jnp-vs-pallas bit-exactness
-    is structural, not coincidental.  Spins ride the sweep `fori_loop` as
-    the carry; the effective fields are a pure function of the final spins
-    and are recomputed ONCE per launch by the dense `metropolis.lane_h_eff`
-    (identical to the jnp backend's per-sweep recompute of the last sweep).
-
-    The coloring/coupling tables arrive as trailing input refs (Pallas
-    forbids captured array constants); ``tables_treedef`` restores the
-    (classes, h, base_nbr, base_J, tau_J) pytree from their values.
+    Per sweep: stage the uniforms, then visit the rows in class order
+    (``order_ref``), each visit recomputing the row's fields from the
+    current spins and flipping it — the row-by-row form of
+    `metropolis.colored_flip_spins`, with each float op in the same order.
+    The carried fields are a pure function of the final spins and are
+    recomputed once per launch (`metropolis.lane_h_eff`, row by row).
     """
     exp_fn = fx.EXP_FNS[exp_flavor]
+    k = sd + 2  # per-site couplings: h, J_0..J_{sd-1}, tau J (undoubled)
+    blocks = -(-rows // mt.N)
 
-    def body(spins_ref, rng_ref, beta_ref, *refs):
-        *table_refs, o_spins_ref, o_hs_ref, o_ht_ref, o_rng_ref = refs
-        classes, h, base_nbr, base_J, tau_J = jax.tree_util.tree_unflatten(
-            tables_treedef, [r[...] for r in table_refs]
-        )
-        o_rng_ref[...] = rng_ref[...]
-        beta_vec = beta_ref[...].reshape(bt)
+    def body(nbr_ref, order_ref, cpl_ref, beta_ref, spins_ref, rng_ref,
+             o_spins, o_hs, o_ht, o_rng, u_ref):
+        o_spins[...] = spins_ref[...]
+        o_rng[...] = rng_ref[...]
+        beta = beta_ref[:, 0, :]  # (bt, 128)
+        coef = _coupling_reader(cpl_ref, k, multi)
 
-        def sweep_step(_k, s):
-            s_rng, u = _draw_sweep_uniforms(o_rng_ref[...], blocks, rows)
-            o_rng_ref[...] = s_rng
-            u_t = u.reshape(rows, bt, LANES).transpose(1, 0, 2)
-            return jax.vmap(
-                lambda sb, ub, bb: mp.colored_flip_spins(
-                    sb, ub, bb, classes, exp_fn
-                )
-            )(s, u_t, beta_vec)
+        def fields(r):
+            i = lax.rem(r, n)
+            base = r - i
+            hs = jnp.broadcast_to(coef(i, 0), (bt, LANES))
+            for d in range(sd):
+                hs = hs + coef(i, 1 + d) * o_spins[:, base + nbr_ref[i * sd + d], :]
+            first, last = r < n, r >= rows - n  # section-boundary layer blocks
+            down = o_spins[:, jnp.where(first, rows - n + i, r - n), :]
+            down = jnp.where(first, pltpu.roll(down, 1, 1), down)
+            up = o_spins[:, jnp.where(last, i, r + n), :]
+            up = jnp.where(last, pltpu.roll(up, LANES - 1, 1), up)
+            return hs, coef(i, sd + 1) * (down + up)
 
-        s = lax.fori_loop(0, num_sweeps, sweep_step, spins_ref[...])
-        o_spins_ref[...] = s
-        hs, ht = jax.vmap(
-            lambda sb: mp.lane_h_eff(sb, h, base_nbr, base_J, tau_J, n)
-        )(s)
-        o_hs_ref[...] = hs
-        o_ht_ref[...] = ht
+        def flip_row(j, carry):
+            r = order_ref[j]
+            s = o_spins[:, r, :]
+            hs, ht = fields(r)
+            x = (f32(-2.0) * beta) * s * (hs + ht)
+            mask = (u_ref[:, r, :] < exp_fn(x)).astype(f32)
+            o_spins[:, r, :] = s * (f32(1.0) - f32(2.0) * mask)
+            return carry
+
+        def sweep(_k, carry):
+            _draw_uniforms(o_rng, u_ref, bt, rows, blocks)
+            lax.fori_loop(0, rows, flip_row, 0)
+            return carry
+
+        lax.fori_loop(0, num_sweeps, sweep, 0)
+
+        def refresh_row(r, carry):
+            hs, ht = fields(r)
+            o_hs[:, r, :] = hs
+            o_ht[:, r, :] = ht
+            return carry
+
+        lax.fori_loop(0, rows, refresh_row, 0)
 
     return body
+
+
+def _cb_call(
+    spins, rng, beta, base_nbr, order, cpl,
+    *, n, num_sweeps, exp_flavor, interpret, replica_tile, multi,
+):
+    B, rows, lanes = spins.shape
+    assert lanes == LANES, spins.shape
+    assert rng.shape == (mt.N, B * LANES), (rng.shape, B)
+    sd = base_nbr.shape[-1]
+    k = sd + 2
+    per = replica_vmem_bytes(rows, n, k, fields_in=False, multi=multi)
+    bt = _tile(B, replica_tile, per)
+    body = _cb_body(n, sd, rows, bt, num_sweeps, exp_flavor, multi)
+    tile = pl.BlockSpec((bt, rows, LANES), lambda g: (g, 0, 0))
+    gen = pl.BlockSpec((mt.N, bt * LANES), lambda g: (0, g))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    field = jax.ShapeDtypeStruct((B, rows, LANES), f32)
+    return pl.pallas_call(
+        body,
+        out_shape=(field, field, field, jax.ShapeDtypeStruct(rng.shape, jnp.uint32)),
+        grid=(B // bt,),
+        in_specs=[
+            smem,
+            smem,
+            pl.BlockSpec((bt, n, k), lambda g: (g, 0, 0)) if multi else smem,
+            pl.BlockSpec((bt, 1, LANES), lambda g: (g, 0, 0)),
+            tile,
+            gen,
+        ],
+        out_specs=(tile, tile, tile, gen),
+        scratch_shapes=[pltpu.VMEM((bt, rows, LANES), f32)],
+        compiler_params=_compiler_params(bt, per),
+        interpret=interpret,
+    )(
+        jnp.reshape(base_nbr, (-1,)).astype(jnp.int32), order, cpl,
+        _beta_lanes(beta, B), spins, rng,
+    )
+
+
+def _visit_order(classes) -> jax.Array:
+    """Row visit order of one colored sweep: class by class, rows ascending."""
+    return jnp.asarray(np.concatenate([np.asarray(c.rows) for c in classes]), jnp.int32)
 
 
 def make_colored_multisweep_kernel(
@@ -528,123 +507,49 @@ def make_colored_multisweep_kernel(
     tau_J,  # (n,) f32 NOT doubled
     n: int,
     exp_flavor: str = "fast",
-    interpret: bool = True,
+    *,
+    interpret: bool,
     replica_tile: int | None = None,
 ):
     """Build the fused colored-sweep entry for one model.
 
-    The coloring and coupling tables are closed over per model (like the
-    body itself) and shipped as shared kernel inputs, so the returned
-    callable is simply ``fn(spins, rng, beta, num_sweeps) -> (spins,
-    h_space, h_tau, rng)`` with ``num_sweeps`` static.  Unlike the
-    sequential kernels there are no h_space/h_tau *inputs*: the colored
-    sweep recomputes fields from spins (DESIGN.md §Coloring), so shipping
-    them in would be dead HBM traffic.
+    The visit order and coupling tables are built once per model and
+    shipped as SMEM kernel inputs (Pallas forbids captured array
+    constants), so the returned callable is simply ``fn(spins, rng, beta,
+    num_sweeps) -> (spins, h_space, h_tau, rng)`` with ``num_sweeps``
+    static.  Unlike the sequential kernels there are no h_space/h_tau
+    *inputs*: the colored sweep recomputes fields from spins (DESIGN.md
+    §Coloring), so shipping them in would be dead HBM traffic.
     """
-    tables = (
-        jax.tree_util.tree_map(jnp.asarray, tuple(classes)),
-        jnp.asarray(h, jnp.float32),
-        jnp.asarray(base_nbr, jnp.int32),
-        jnp.asarray(base_J, jnp.float32),
-        jnp.asarray(tau_J, jnp.float32),
-    )
-    table_leaves, tables_treedef = jax.tree_util.tree_flatten(tables)
+    order = _visit_order(classes)
+    cpl = jnp.concatenate(
+        [
+            jnp.reshape(jnp.asarray(h, f32), (n, 1)),
+            jnp.asarray(base_J, f32),
+            jnp.reshape(jnp.asarray(tau_J, f32), (n, 1)),
+        ],
+        axis=1,
+    ).reshape(-1)
+    nbr = jnp.asarray(base_nbr, jnp.int32)
 
     @functools.partial(jax.jit, static_argnums=(3,))
     def fn(spins, rng, beta, num_sweeps):
-        B, rows, lanes = spins.shape
-        assert lanes == LANES, spins.shape
-        assert rng.shape == (mt.N, B * LANES), (rng.shape, B)
-        bt = B if replica_tile is None else replica_tile
-        if B % bt != 0:
-            raise ValueError(f"replica_tile {bt} must divide batch {B}")
-        blocks = -(-rows // mt.N)  # ceil
-        body = _make_colored_body(
-            tables_treedef, n, rows, bt, blocks, num_sweeps, exp_flavor
+        return _cb_call(
+            spins, rng, beta, nbr, order, cpl,
+            n=n, num_sweeps=num_sweeps, exp_flavor=exp_flavor,
+            interpret=interpret, replica_tile=replica_tile, multi=False,
         )
-        tile_spec = pl.BlockSpec((bt, rows, LANES), lambda g: (g, 0, 0))
-        rng_spec = pl.BlockSpec((mt.N, bt * LANES), lambda g: (0, g))
-        shared = lambda a: pl.BlockSpec(a.shape, lambda g: (0,) * a.ndim)
-        return pl.pallas_call(
-            body,
-            out_shape=(
-                jax.ShapeDtypeStruct((B, rows, LANES), jnp.float32),
-                jax.ShapeDtypeStruct((B, rows, LANES), jnp.float32),
-                jax.ShapeDtypeStruct((B, rows, LANES), jnp.float32),
-                jax.ShapeDtypeStruct((mt.N, B * LANES), jnp.uint32),
-            ),
-            grid=(B // bt,),
-            in_specs=[
-                tile_spec,
-                rng_spec,
-                pl.BlockSpec((bt, 1), lambda g: (g, 0)),
-                *[shared(a) for a in table_leaves],
-            ],
-            out_specs=(tile_spec, tile_spec, tile_spec, rng_spec),
-            interpret=interpret,
-        )(spins, rng, beta.reshape(-1, 1), *table_leaves)
 
     return fn
 
 
-def _make_colored_multi_body(
-    tables_treedef,
-    n: int,
-    rows: int,
-    bt: int,
-    blocks: int,
-    num_sweeps: int,
-    exp_flavor: str,
-):
-    """Multi-tenant colored-sweep body: like `_make_colored_body`, but the
-    per-model coupling tables (h, base_J, tau_J) arrive as BATCHED input
-    refs with a leading tile dim and the vmap over the replica tile maps
-    over them too, each slot binding its own couplings onto the SHARED
-    structural color classes (`metropolis.bind_class_tables` — the same
-    binding the jnp backend vmaps, so the backends stay bit-identical).
-    """
-    exp_fn = fx.EXP_FNS[exp_flavor]
-
-    def body(spins_ref, rng_ref, beta_ref, h_ref, bJ_ref, tJ_ref, *refs):
-        *table_refs, o_spins_ref, o_hs_ref, o_ht_ref, o_rng_ref = refs
-        classes, base_nbr = jax.tree_util.tree_unflatten(
-            tables_treedef, [r[...] for r in table_refs]
-        )
-        h_b, bJ_b, tJ_b = h_ref[...], bJ_ref[...], tJ_ref[...]
-        o_rng_ref[...] = rng_ref[...]
-        beta_vec = beta_ref[...].reshape(bt)
-        # Gathered ONCE per launch — loop-invariant, must not ride the
-        # per-sweep loop (the jnp backend hoists identically; same values
-        # either way, so still bit-exact).
-        cls_tabs_b = mp.class_coupling_slices(classes, h_b, bJ_b, tJ_b, n)
-
-        def flip_one(sb, ub, bb, *cls_tabs):
-            bound = mp.bind_class_tables(classes, cls_tabs)
-            return mp.colored_flip_spins(sb, ub, bb, bound, exp_fn)
-
-        def sweep_step(_k, s):
-            s_rng, u = _draw_sweep_uniforms(o_rng_ref[...], blocks, rows)
-            o_rng_ref[...] = s_rng
-            u_t = u.reshape(rows, bt, LANES).transpose(1, 0, 2)
-            return jax.vmap(flip_one)(s, u_t, beta_vec, *cls_tabs_b)
-
-        s = lax.fori_loop(0, num_sweeps, sweep_step, spins_ref[...])
-        o_spins_ref[...] = s
-        hs, ht = jax.vmap(
-            lambda sb, hb, jb, tb: mp.lane_h_eff(sb, hb, base_nbr, jb, tb, n)
-        )(s, h_b, bJ_b, tJ_b)
-        o_hs_ref[...] = hs
-        o_ht_ref[...] = ht
-
-    return body
-
-
 def make_colored_multisweep_multi_kernel(
-    classes,  # tuple of reorder.ColorClass (host numpy; structure + defaults)
+    classes,  # tuple of reorder.ColorClass (host numpy; structure)
     base_nbr,  # (n, SD) int32 — SHARED topology
     n: int,
     exp_flavor: str = "fast",
-    interpret: bool = True,
+    *,
+    interpret: bool,
     replica_tile: int | None = None,
 ):
     """Build the multi-tenant fused colored-sweep entry for one TOPOLOGY.
@@ -653,53 +558,27 @@ def make_colored_multisweep_multi_kernel(
     (spins, h_space, h_tau, rng)`` with the per-slot coupling tables as
     runtime ``[B, ...]`` inputs — unlike `make_colored_multisweep_kernel`,
     which closes over one model's couplings, this callable serves any
-    model mix sharing the structural classes' lattice.
+    model mix sharing the classes' lattice.
     """
-    tables = (
-        jax.tree_util.tree_map(jnp.asarray, tuple(classes)),
-        jnp.asarray(base_nbr, jnp.int32),
-    )
-    table_leaves, tables_treedef = jax.tree_util.tree_flatten(tables)
+    order = _visit_order(classes)
+    nbr = jnp.asarray(base_nbr, jnp.int32)
 
     @functools.partial(jax.jit, static_argnums=(6,))
     def fn(spins, rng, beta, h_b, base_J_b, tau_J_b, num_sweeps):
-        B, rows, lanes = spins.shape
-        assert lanes == LANES, spins.shape
-        assert rng.shape == (mt.N, B * LANES), (rng.shape, B)
+        B = spins.shape[0]
         assert h_b.shape[0] == B and base_J_b.shape[0] == B
-        bt = B if replica_tile is None else replica_tile
-        if B % bt != 0:
-            raise ValueError(f"replica_tile {bt} must divide batch {B}")
-        blocks = -(-rows // mt.N)  # ceil
-        body = _make_colored_multi_body(
-            tables_treedef, n, rows, bt, blocks, num_sweeps, exp_flavor
-        )
-        tile_spec = pl.BlockSpec((bt, rows, LANES), lambda g: (g, 0, 0))
-        rng_spec = pl.BlockSpec((mt.N, bt * LANES), lambda g: (0, g))
-        shared = lambda a: pl.BlockSpec(a.shape, lambda g: (0,) * a.ndim)
-        return pl.pallas_call(
-            body,
-            out_shape=(
-                jax.ShapeDtypeStruct((B, rows, LANES), jnp.float32),
-                jax.ShapeDtypeStruct((B, rows, LANES), jnp.float32),
-                jax.ShapeDtypeStruct((B, rows, LANES), jnp.float32),
-                jax.ShapeDtypeStruct((mt.N, B * LANES), jnp.uint32),
-            ),
-            grid=(B // bt,),
-            in_specs=[
-                tile_spec,
-                rng_spec,
-                pl.BlockSpec((bt, 1), lambda g: (g, 0)),
-                pl.BlockSpec((bt, n), lambda g: (g, 0)),
-                pl.BlockSpec((bt, n, base_J_b.shape[2]), lambda g: (g, 0, 0)),
-                pl.BlockSpec((bt, n), lambda g: (g, 0)),
-                *[shared(a) for a in table_leaves],
+        cpl = jnp.concatenate(
+            [
+                jnp.reshape(h_b, (B, n, 1)).astype(f32),
+                base_J_b.astype(f32),
+                jnp.reshape(tau_J_b, (B, n, 1)).astype(f32),
             ],
-            out_specs=(tile_spec, tile_spec, tile_spec, rng_spec),
-            interpret=interpret,
-        )(
-            spins, rng, beta.reshape(-1, 1), h_b, base_J_b, tau_J_b,
-            *table_leaves,
+            axis=2,
+        )
+        return _cb_call(
+            spins, rng, beta, nbr, order, cpl,
+            n=n, num_sweeps=num_sweeps, exp_flavor=exp_flavor,
+            interpret=interpret, replica_tile=replica_tile, multi=True,
         )
 
     return fn

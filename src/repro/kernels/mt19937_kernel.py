@@ -72,7 +72,7 @@ def _block_call(body, state, out_dtype, interpret):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def mt_next_block_kernel(state: jax.Array, interpret: bool = True):
+def mt_next_block_kernel(state: jax.Array, *, interpret: bool):
     """Advance interlaced state (624, V) with V a multiple of 128.
 
     Returns (new_state, tempered uint32 outputs), both (624, V).
@@ -81,7 +81,7 @@ def mt_next_block_kernel(state: jax.Array, interpret: bool = True):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def mt_uniforms_kernel(state: jax.Array, interpret: bool = True):
+def mt_uniforms_kernel(state: jax.Array, *, interpret: bool):
     """Advance interlaced state and emit float32 uniforms directly.
 
     The temper + 24-bit float conversion is fused into the kernel — one
@@ -90,7 +90,7 @@ def mt_uniforms_kernel(state: jax.Array, interpret: bool = True):
     return _block_call(_mt_uniform_body, state, jnp.float32, interpret)
 
 
-def mt_uniform_blocks_kernel(state: jax.Array, num_blocks: int, interpret: bool = True):
+def mt_uniform_blocks_kernel(state: jax.Array, num_blocks: int, *, interpret: bool):
     """Bulk uniforms via the fused kernel: scan of kernel steps (paper §2.3)."""
 
     def step(s, _):

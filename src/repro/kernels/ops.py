@@ -54,6 +54,14 @@ def mt_next_block(state: jax.Array, interpret=None):
     return new_state[:, :v], out[:, :v]
 
 
+def mt_uniform_blocks(state: jax.Array, num_blocks: int, interpret=None):
+    """``num_blocks`` blocks of uniforms from (624, V) interlaced state, V a
+    multiple of 128, via the fused twist+temper+convert kernel."""
+    return mt19937_kernel.mt_uniform_blocks_kernel(
+        state, num_blocks, interpret=_auto_interpret(interpret)
+    )
+
+
 def metropolis_sweep(
     spins,
     h_space,
@@ -79,11 +87,11 @@ def metropolis_sweep(
         u,
         base_nbr,
         base_J2,
-        jnp.reshape(tau_J2, (-1, 1)),
-        jnp.reshape(beta, (-1, 1)),
+        tau_J2,
+        beta,
         n,
         exp_flavor,
-        interpret,
+        interpret=interpret,
     )
 
 
@@ -117,13 +125,13 @@ def metropolis_multisweep(
         rng,
         base_nbr,
         base_J2,
-        jnp.reshape(tau_J2, (-1, 1)),
-        jnp.reshape(beta, (-1, 1)),
+        tau_J2,
+        beta,
         n,
         num_sweeps,
         exp_flavor,
-        interpret,
-        replica_tile,
+        interpret=interpret,
+        replica_tile=replica_tile,
     )
 
 
@@ -148,7 +156,6 @@ def metropolis_multisweep_multi(
     (spins, h_space, h_tau, rng).
     """
     interpret = _auto_interpret(interpret)
-    B = spins.shape[0]
     return metropolis_kernel.metropolis_multisweep_multi_kernel(
         spins,
         h_space,
@@ -156,13 +163,13 @@ def metropolis_multisweep_multi(
         rng,
         base_nbr,
         base_J2_b,
-        jnp.reshape(tau_J2_b, (B, -1, 1)),
-        jnp.reshape(beta, (-1, 1)),
+        tau_J2_b,
+        beta,
         n,
         num_sweeps,
         exp_flavor,
-        interpret,
-        replica_tile,
+        interpret=interpret,
+        replica_tile=replica_tile,
     )
 
 
@@ -194,8 +201,8 @@ def make_colored_multisweep(
         tau_J,
         n,
         exp_flavor,
-        interpret,
-        replica_tile,
+        interpret=interpret,
+        replica_tile=replica_tile,
     )
 
 
@@ -219,8 +226,8 @@ def make_colored_multisweep_multi(
         base_nbr,
         n,
         exp_flavor,
-        interpret,
-        replica_tile,
+        interpret=interpret,
+        replica_tile=replica_tile,
     )
 
 

@@ -53,6 +53,7 @@ import time
 import numpy as np
 
 from repro.core import ising
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve_mc import AnnealJob, PTJob, SampleServer
 
 
@@ -151,6 +152,7 @@ def main(argv=None):
                          "--snapshot-dir and finish its recorded jobs "
                          "instead of submitting a fresh mix")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.smoke:
         # 7 anneal jobs + 1 three-replica PT job = 8 jobs on 4 slots.
         args.jobs, args.slots, args.chunk = 7, 4, 4

@@ -28,7 +28,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.nn.basic import mlp_apply, mlp_init
 from repro.nn.param import Param, fan_in_init
-from repro.sharding import current_ctx, shard_map
+from repro.sharding import current_ctx
 
 f32 = jnp.float32
 
@@ -222,11 +222,12 @@ def moe_apply(
             P("model", None, None),
             P(batch_axes, None, None),
         )
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             shard_fn,
-            mesh,
+            mesh=mesh,
             in_specs=in_specs,
             out_specs=(P(batch_axes, None, None), P()),
+            check_vma=False,
         )(p["router"], rb, p["wi"], p["wg"], p["wo"], x)
     else:
         y, aux = local_moe(

@@ -15,6 +15,7 @@ from repro.serve_mc.scheduler import (
     AdmissionPolicy,
     PlacementPlanner,
     PriorityBackfillPolicy,
+    ProfilerError,
     SampleServer,
     ServeConfig,
     SlotPool,
@@ -30,6 +31,7 @@ __all__ = [
     "PTJob",
     "PlacementPlanner",
     "PriorityBackfillPolicy",
+    "ProfilerError",
     "SampleServer",
     "ServeConfig",
     "SlotPool",

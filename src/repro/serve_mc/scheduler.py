@@ -881,6 +881,19 @@ class AdaptiveChunker:
             self.per_sweep_ewma += self.alpha * (per_sweep - self.per_sweep_ewma)
 
 
+class ProfilerError(RuntimeError):
+    """An armed profiler trace failed to start or stop.
+
+    ``results`` holds the jobs that retired in the `step` or `drain` call
+    that raised it: they have left the server, so this is the caller's
+    only copy of them.
+    """
+
+    def __init__(self, message: str, results=()):
+        super().__init__(message)
+        self.results: List[JobResult] = list(results)
+
+
 @dataclasses.dataclass(frozen=True)
 class ServeConfig:
     """Every `SampleServer` construction knob as one value object.
@@ -1407,8 +1420,12 @@ class SampleServer:
         ops, fusion, memory — TensorBoard/Perfetto-loadable under
         ``logdir``) that the host-side Chrome trace cannot see.  The
         window opens right before the next launch and closes after the
-        Nth; start/stop failures are reported as trace events, never
-        raised — profiling must not kill a resident server."""
+        Nth.  A start or stop that fails disarms the window and raises
+        `ProfilerError` from `step` and `drain`: a profile that was asked
+        for and not taken is an error.  The window opens after admission
+        and closes at the end of a step, so the server's state stays
+        consistent, the error carries the jobs retired so far, and a
+        later `step` or `drain` carries on."""
         if num_chunks < 1:
             raise ValueError(f"num_chunks must be >= 1, got {num_chunks}")
         self._profiler = {
@@ -1434,14 +1451,16 @@ class SampleServer:
         overhead bench compares the two).
         """
         tel = self.telemetry
-        if self._profiler is not None and not self._profiler["active"]:
+        prof = self._profiler
+        if prof is not None and not prof["active"]:
+            self._profiler = None  # a failed start leaves nothing armed
             try:
-                jax.profiler.start_trace(self._profiler["logdir"])
-                self._profiler["active"] = True
-                tel.instant("profiler.start", logdir=self._profiler["logdir"])
-            except Exception as e:  # pragma: no cover - environment-dependent
-                tel.instant("profiler.error", error=str(e))
-                self._profiler = None
+                jax.profiler.start_trace(prof["logdir"])
+            except Exception as e:
+                raise ProfilerError(f"profiler trace did not start: {e}") from e
+            prof["active"] = True
+            self._profiler = prof
+            tel.instant("profiler.start", logdir=prof["logdir"])
         timed = self._chunker is not None or tel.enabled
         pending = None
         if not timed:
@@ -1502,13 +1521,22 @@ class SampleServer:
             )
         if self._profiler is not None and self._profiler["active"]:
             self._profiler["remaining"] -= 1
-            if self._profiler["remaining"] <= 0:
-                try:
-                    jax.profiler.stop_trace()
-                    tel.instant("profiler.stop")
-                except Exception as e:  # pragma: no cover
-                    tel.instant("profiler.error", error=str(e))
-                self._profiler = None
+
+    def _close_profiler_window(self, completed: List[JobResult]) -> None:
+        """Stop an armed profiler trace whose window of launches is
+        spent; runs at the end of a step, so a failure raises with the
+        step's bookkeeping already complete and its retired jobs
+        (``completed``) in the error."""
+        prof = self._profiler
+        if prof is not None and prof["active"] and prof["remaining"] <= 0:
+            self._profiler = None
+            try:
+                jax.profiler.stop_trace()
+            except Exception as e:
+                raise ProfilerError(
+                    f"profiler trace did not stop: {e}", completed
+                ) from e
+            self.telemetry.instant("profiler.stop")
 
     def step(self) -> List[JobResult]:
         """One scheduling round: admit, one chunked launch, hooks, retire.
@@ -1578,6 +1606,7 @@ class SampleServer:
                 # pool gather is synchronous (it must see THIS boundary),
                 # the fsync'd writes ride the manager's writer thread.
                 self.snapshot(blocking=False)
+            self._close_profiler_window(completed)
         return completed
 
     def drain(self, max_steps: int = 1_000_000) -> List[JobResult]:
@@ -1607,7 +1636,11 @@ class SampleServer:
                     self.snapshot(blocking=True)
                 self.preempted = True
                 return results
-            results.extend(self.step())
+            try:
+                results.extend(self.step())
+            except ProfilerError as e:
+                e.results[:0] = results
+                raise
         raise RuntimeError(f"drain did not converge in {max_steps} steps")
 
     # -- snapshot / restore (serve_mc/snapshot.py; DESIGN.md §Recovery) -------

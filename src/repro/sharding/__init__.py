@@ -4,6 +4,5 @@ from repro.sharding.ctx import (  # noqa: F401
     logical_sharding,
     set_ctx,
     shard_constraint,
-    shard_map,
     use_ctx,
 )

@@ -10,13 +10,6 @@ Divisibility guard: a logical axis only maps to a physical mesh axis when the
 dimension size divides evenly; otherwise it silently falls back to
 replication.  This is what makes e.g. gemma's single KV head (kv=1) lower
 cleanly on a 16-wide model axis while qwen's 8 KV heads shard where they can.
-
-`shard_map` is the version-portable entry point every consumer in this
-repo uses (moe expert parallelism, the compressed gradient sync, the
-mesh-sharded SweepEngine): jax >= 0.5 exposes ``jax.shard_map`` while the
-0.4.x line only has ``jax.experimental.shard_map.shard_map`` with the
-older ``check_rep`` keyword — one wrapper here instead of a hasattr gate
-at every call site.
 """
 
 from __future__ import annotations
@@ -29,26 +22,6 @@ from typing import Mapping, Optional, Sequence, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-
-def shard_map(f, mesh: Mesh, *, in_specs, out_specs):
-    """Version-portable `shard_map` (replication checking disabled).
-
-    ``jax.shard_map`` (>= 0.5) and ``jax.experimental.shard_map`` (0.4.x)
-    take the same (f, mesh, in_specs, out_specs) but spell the
-    replication-check escape hatch differently (``check_vma`` vs
-    ``check_rep``); the check is disabled on both paths because the sweep
-    kernels and collectives here manage replication explicitly.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
 
 # Default logical -> physical mapping.  "pod" multiplies the batch axes when
 # present (multi-pod meshes); tensor-parallel axes all map to "model".

@@ -31,7 +31,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.models import decoder, encdec
 from repro.optim.adamw import AdamWConfig, OptState, adamw_update, init_opt_state
-from repro.sharding import current_ctx, shard_map
+from repro.sharding import current_ctx
 
 f32 = jnp.float32
 
@@ -178,11 +178,12 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig, param_specs=None):
                 specs = jax.tree_util.tree_map(lambda _: P(), grads)
             else:
                 specs = param_specs
-            grads, ef = shard_map(
+            grads, ef = jax.shard_map(
                 sync,
-                mesh,
+                mesh=mesh,
                 in_specs=(specs, specs),
                 out_specs=(specs, specs),
+                check_vma=False,
             )(grads, ef)
         new_params, new_opt, opt_metrics = adamw_update(
             tc.optimizer, state.params, grads, state.opt, state.step

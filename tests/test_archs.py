@@ -72,20 +72,7 @@ def test_arch_smoke_forward_and_train_step(arch):
 
 @pytest.mark.parametrize(
     "arch",
-    [
-        "qwen2.5-14b",
-        pytest.param(
-            "deepseek-v3-671b",
-            marks=pytest.mark.skipif(
-                tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5),
-                reason="known MLA-absorbed decode mismatch on jax 0.4.x "
-                "(err ~0.4 at t=3); version-gated until the numeric delta "
-                "is root-caused",
-            ),
-        ),
-        "zamba2-1.2b",
-        "rwkv6-1.6b",
-    ],
+    ["qwen2.5-14b", "deepseek-v3-671b", "zamba2-1.2b", "rwkv6-1.6b"],
 )
 def test_arch_decode_matches_teacher_forcing(arch):
     """KV-cache / SSM-state / MLA-absorbed decode must reproduce the

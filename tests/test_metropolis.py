@@ -152,6 +152,42 @@ def test_pallas_kernel_h_eff_invariant():
     )
 
 
+KERNEL_ENTRIES = [
+    "metropolis_kernel.metropolis_sweep_kernel",
+    "metropolis_kernel.metropolis_multisweep_kernel",
+    "metropolis_kernel.metropolis_multisweep_multi_kernel",
+    "metropolis_kernel.make_colored_multisweep_kernel",
+    "metropolis_kernel.make_colored_multisweep_multi_kernel",
+    "mt19937_kernel.mt_next_block_kernel",
+    "mt19937_kernel.mt_uniforms_kernel",
+    "mt19937_kernel.mt_uniform_blocks_kernel",
+    "fastexp_kernel.fastexp_2d",
+]
+
+
+@pytest.mark.parametrize("entry", KERNEL_ENTRIES)
+def test_kernel_entry_has_no_interpret_default(entry):
+    """Only `ops._auto_interpret` chooses interpret mode: a caller that
+    goes around `ops` has to say which mode it wants."""
+    import importlib
+    import inspect
+
+    module, name = entry.split(".")
+    fn = getattr(importlib.import_module(f"repro.kernels.{module}"), name)
+    param = inspect.signature(fn).parameters["interpret"]
+    assert param.kind is inspect.Parameter.KEYWORD_ONLY
+    assert param.default is inspect.Parameter.empty
+
+
+def test_auto_interpret_is_compiled_on_tpu_unless_asked(monkeypatch):
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert ops._auto_interpret(None) is False
+    assert ops._auto_interpret(True) is True
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "cpu")
+    assert ops._auto_interpret(None) is True
+    assert ops._auto_interpret(False) is False
+
+
 def test_reorder_roundtrip():
     m = ising.random_layered_model(n=4, L=8, seed=0)
     x = np.arange(m.num_spins, dtype=np.int64)

@@ -22,7 +22,7 @@ from repro.obs import (
     validate_events,
 )
 from repro.obs.trace import REQUIRED_FIELDS
-from repro.serve_mc import AnnealJob, PTJob, SampleServer
+from repro.serve_mc import AnnealJob, PTJob, ProfilerError, SampleServer
 
 MODEL = ising.random_layered_model(n=5, L=8, seed=1, beta=1.0)
 MIXED = [(10, 9), (11, 7), (12, 5)]  # (seed, budget)
@@ -415,8 +415,35 @@ def test_profiler_failure_never_kills_serving(monkeypatch, tmp_path):
     srv = _server(slots=1, chunk_sweeps=2, policy="fifo")
     srv.arm_profiler(tmp_path / "prof")
     srv.submit(AnnealJob.constant(seed=5, sweeps=4, beta=1.0))
-    (r,) = srv.drain()  # must complete despite the profiler error
+    # A profile that was asked for and not taken raises to the caller ...
+    with pytest.raises(ProfilerError, match="unavailable") as err:
+        srv.drain()
+    assert err.value.results == []
+    assert srv._profiler is None and srv.sweeps_elapsed == 0
+    # ... and the server, left consistent and disarmed, serves on.
+    (r,) = srv.drain()
     assert r.sweeps_done == 4
-    errors = [e for e in srv.telemetry.events()
-              if e["name"] == "profiler.error"]
-    assert len(errors) == 1 and "unavailable" in errors[0]["args"]["error"]
+    names = [e["name"] for e in srv.telemetry.events()]
+    assert "profiler.start" not in names
+
+
+@pytest.mark.parametrize("num_chunks", [1, 2])
+def test_profiler_stop_failure_raises_after_the_step(monkeypatch, tmp_path, num_chunks):
+    def boom():
+        raise RuntimeError("stop failed")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda logdir: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", boom)
+    srv = _server(slots=1, chunk_sweeps=2, policy="fifo")
+    srv.arm_profiler(tmp_path / "prof", num_chunks=num_chunks)
+    first = srv.submit(AnnealJob.constant(seed=5, sweeps=2, beta=1.0))
+    second = srv.submit(AnnealJob.constant(seed=6, sweeps=4, beta=1.0))
+    with pytest.raises(ProfilerError, match="stop failed") as err:
+        srv.drain()
+    # The failing step finished its bookkeeping before the stop raised,
+    # and the job that retired first -- in the failing step (1 chunk) or
+    # in an earlier step of the same drain (2) -- is in the error.
+    assert srv._profiler is None and srv.sweeps_elapsed == 2 * num_chunks
+    assert [r.jid for r in err.value.results] == [first]
+    (r,) = srv.drain()
+    assert r.jid == second and r.sweeps_done == 4

@@ -20,6 +20,31 @@ def test_lane_energy_matches_reference():
     assert abs(e_lane - ising.energy(m, sp)) < 1e-3 * max(1, abs(ising.energy(m, sp)))
 
 
+def test_lane_energy_sum_order_is_fixed():
+    """The energy is the same pairwise tree of f32 adds whatever batch it
+    is vmapped over: the swap phase of a ladder spread over devices sums
+    per-device batches, a resident ladder sums its own."""
+    import jax
+
+    m = ising.random_layered_model(n=6, L=8, seed=3, beta=0.7)
+    tabs = [jnp.asarray(a) for a in (m.h, m.space_nbr, m.space_J, m.tau_J)]
+    spins = np.stack([
+        np.asarray(metropolis.make_lane_state(m, ising.init_spins(m, k), 4).spins)
+        for k in range(6)
+    ])
+    energy = jax.jit(jax.vmap(lambda s: tempering.lane_energy(s, *tabs, m.n)))
+    whole = np.asarray(energy(jnp.asarray(spins)))
+    for b in range(6):
+        np.testing.assert_array_equal(np.asarray(energy(jnp.asarray(spins[b:b + 1]))),
+                                      whole[b:b + 1])
+    # The tree itself: 13 values padded to 16, halved four times.
+    x = np.arange(1, 14, dtype=np.float32) / np.float32(7)
+    tree = np.concatenate([x, np.zeros(3, np.float32)])
+    while tree.size > 1:
+        tree = tree[: tree.size // 2] + tree[tree.size // 2 :]
+    assert np.asarray(tempering._pairwise_sum(jnp.asarray(x))) == tree[0]
+
+
 def test_pt_round_runs_and_swaps():
     m = ising.random_layered_model(n=6, L=8, seed=3)
     betas = np.linspace(0.2, 2.5, 8)
