@@ -98,6 +98,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import ising, metropolis, mt19937 as mt, reorder
+from repro.obs.telemetry import Telemetry
 
 f32 = jnp.float32
 
@@ -388,6 +389,9 @@ class SweepEngine:
         self.model = model
         self.rung = rung
         self.backend = backend
+        # Where the engine's host-side spans go: a server hands its own
+        # registry over; alone, the engine records nothing.
+        self.telemetry = Telemetry(enabled=False)
         self.batch = batch  # LOGICAL slot count — what every public API sees
         self.V = V
         self.exp_flavor = exp_flavor
@@ -1406,12 +1410,13 @@ class SweepEngine:
     def set_slot_betas(self, carry: SweepCarry, slots, betas) -> SweepCarry:
         """Rewrite the betas of the given slots (anneal-schedule advance,
         tempering swaps) without touching spins, fields, or RNG."""
-        idx = jnp.asarray(self.phys_slots(slots).astype(np.int32))
-        vals = jnp.asarray(betas, f32)
-        new = carry.betas.at[idx].set(vals)
-        if self.mesh is not None:  # keep the betas row sharded
-            new = jax.device_put(new, NamedSharding(self.mesh, P("data")))
-        return carry._replace(betas=new)
+        with self.telemetry.span("engine.set_betas", cat="engine"):
+            idx = jnp.asarray(self.phys_slots(slots).astype(np.int32))
+            vals = jnp.asarray(betas, f32)
+            new = carry.betas.at[idx].set(vals)
+            if self.mesh is not None:  # keep the betas row sharded
+                new = jax.device_put(new, NamedSharding(self.mesh, P("data")))
+            return carry._replace(betas=new)
 
     # -- per-slot model tables (the multi-tenant admit API) --------------------
     #

@@ -23,9 +23,12 @@ to a ``deque(maxlen=...)`` — a long-lived server can trace forever and
 hold only the most recent window; ``dropped_events`` counts what the ring
 evicted so truncation is visible, never silent.  Three event shapes:
 
-  * sync spans   (`span` -> ph "B"/"E"): scheduler phases on one track;
-                 properly nested per tid by construction (a context
-                 manager owns the B/E pairing).
+  * sync spans   (`span` -> ph "B"/"E"): scheduler, job-hook and engine
+                 phases on one track; properly nested per tid by
+                 construction (a context manager owns the B/E pairing).
+                 Each span also enters a `jax.profiler.TraceAnnotation`
+                 of its name, so a profiler trace holds it on the device
+                 trace's own clock (a no-op check when none is running).
   * complete     (`complete` -> ph "X" with ``dur``): engine launches —
                  one event per fused launch with its measured wall time.
   * async spans  (`async_begin`/`async_instant`/`async_end` -> ph
@@ -56,6 +59,7 @@ from collections import deque
 from contextlib import contextmanager
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 #: Reservoir size per histogram: enough for stable p50/p95 over recent
 #: traffic, bounded so a resident server never grows it.
@@ -271,14 +275,18 @@ class Telemetry:
 
     @contextmanager
     def span(self, name: str, tid: int = 0, cat: str = "serve", **args):
-        """Sync span (ph "B"/"E") on track ``tid``; nests by construction."""
+        """Sync span (ph "B"/"E") on track ``tid``; nests by construction.
+
+        The span is also a profiler annotation of the same name, so a
+        `jax.profiler` trace places it beside the device's operations."""
         if not self.enabled:
             yield
             return
         self._span_stacks.setdefault(tid, []).append(name)
         self._emit(self._base(name, "B", tid, cat, None, args))
         try:
-            yield
+            with TraceAnnotation(name):
+                yield
         finally:
             top = self._span_stacks[tid].pop()
             assert top == name, f"span stack corrupted: {top} != {name}"

@@ -352,16 +352,19 @@ class AnnealJob(_ScheduledJob):
         ]
 
     def on_segment(self, server, carry, slots):
-        if self.done:
-            return carry
-        return server.engine.set_slot_betas(
-            carry, slots, [self.current_beta(server)]
-        )
+        with server.telemetry.span("anneal.hook"):
+            if self.done:
+                return carry
+            return server.engine.set_slot_betas(
+                carry, slots, [self.current_beta(server)]
+            )
 
     def finalize(self, server, slots) -> JobResult:
         eng, m = server.engine, self.model_on(server)
-        sub = eng.extract_slot(server.carry, slots[0])
-        spins = eng.spins_flat(sub)[0]
+        with server.telemetry.span("engine.extract", cat="engine"):
+            sub = eng.extract_slot(server.carry, slots[0])
+            spins = eng.spins_flat(sub)[0]
+            final_beta = float(np.asarray(sub.betas)[0])
         return JobResult(
             jid=self.jid,
             spins=spins,
@@ -370,7 +373,7 @@ class AnnealJob(_ScheduledJob):
             sweeps_done=self.sweeps_done,
             chunks=self.chunks,
             extras={
-                "final_beta": float(np.asarray(sub.betas)[0]),
+                "final_beta": final_beta,
                 "preemptions": self.preemptions,
             },
         )
@@ -492,64 +495,70 @@ class PTJob(_ScheduledJob):
         return self._energy_tables
 
     def on_segment(self, server, carry, slots):
-        eng = server.engine
-        parity = (self._seg - 1) % 2  # round index just completed, as the
-        # standalone driver's ``r % 2``
-        # Placement-aware routing: the cross-device energy gather is only
-        # needed when the ladder actually SPANS devices.  A device-local
-        # placement (what affine admission produces) takes the same
-        # in-device `swap_phase` fast path as an unsharded server — its
-        # slot gather touches one device's shard only.  Both paths share
-        # `_swap_decide`, so routing by placement is bit-invisible
-        # (tests/test_placement.py).
-        spans = (
-            eng.mesh is not None
-            and len({eng.slot_device(b) for b in slots}) > 1
-        )
-        if eng.mesh is not None:
-            (server._c_swap_cross if spans else server._c_swap_local).add(1)
-        if spans:
-            # Cross-device path: a ladder spanning devices must NOT gather
-            # its slots' spins (that is the whole carry).  Each device
-            # evaluates its own slots' energies (`slot_energies`, zero
-            # spin movement); only the job's R energy/beta scalars cross
-            # devices, and the swap decision is the same `_swap_decide`
-            # body as `swap_phase` — bit-identical to the resident path.
-            # `slot_energies` is already a LOGICAL (B,) view; the carry's
-            # betas row is PHYSICAL and needs the translated indices.
-            lidx = np.asarray(slots, np.int64)
-            energies = eng.slot_energies(carry)[lidx]
-            betas, self.swap_rng, self.swap_accept, self.swap_propose = (
-                tempering.swap_phase_from_energies(
-                    carry.betas[eng.phys_slots(slots)],
-                    energies,
-                    self.swap_rng,
-                    self.swap_accept,
-                    self.swap_propose,
+        with server.telemetry.span("pt.hook"):
+            eng, tel = server.engine, server.telemetry
+            parity = (self._seg - 1) % 2  # round index just completed, as
+            # the ``r % 2`` of `tempering.run_parallel_tempering`
+            # Placement-aware routing: the cross-device energy gather is only
+            # needed when the ladder actually SPANS devices.  A device-local
+            # placement (what affine admission produces) takes the same
+            # in-device `swap_phase` fast path as an unsharded server — its
+            # slot gather touches one device's shard only.  Both paths share
+            # `_swap_decide`, so routing by placement is bit-invisible
+            # (tests/test_placement.py).
+            spans = (
+                eng.mesh is not None
+                and len({eng.slot_device(b) for b in slots}) > 1
+            )
+            if eng.mesh is not None:
+                (server._c_swap_cross if spans else server._c_swap_local).add(1)
+            if spans:
+                # Cross-device path: a ladder spanning devices must NOT gather
+                # its slots' spins (that is the whole carry).  Each device
+                # evaluates its own slots' energies (`slot_energies`, zero
+                # spin movement); only the job's R energy/beta scalars cross
+                # devices, and the swap decision is the same `_swap_decide`
+                # body as `swap_phase` — bit-identical to the resident path.
+                # `slot_energies` is already a LOGICAL (B,) view; the carry's
+                # betas row is PHYSICAL and needs the translated indices.
+                lidx = np.asarray(slots, np.int64)
+                with tel.span("pt.gather"):
+                    energies = eng.slot_energies(carry)[lidx]
+                with tel.span("pt.swap"):
+                    betas, self.swap_rng, self.swap_accept, self.swap_propose = (
+                        tempering.swap_phase_from_energies(
+                            carry.betas[eng.phys_slots(slots)],
+                            energies,
+                            self.swap_rng,
+                            self.swap_accept,
+                            self.swap_propose,
+                            jnp.asarray(parity, jnp.int32),
+                            eng.exp_flavor,
+                        )
+                    )
+                return eng.set_slot_betas(carry, slots, betas)
+            with tel.span("pt.gather"):
+                state = self._gather_state(eng, carry, slots)
+            with tel.span("pt.swap"):
+                state = tempering.swap_phase(
+                    state,
+                    *self._swap_energy_tables(eng),
                     jnp.asarray(parity, jnp.int32),
+                    eng.model.n,
                     eng.exp_flavor,
                 )
-            )
-            return eng.set_slot_betas(carry, slots, betas)
-        state = self._gather_state(eng, carry, slots)
-        state = tempering.swap_phase(
-            state,
-            *self._swap_energy_tables(eng),
-            jnp.asarray(parity, jnp.int32),
-            eng.model.n,
-            eng.exp_flavor,
-        )
-        self.swap_rng = state.swap_rng
-        self.swap_accept = state.swap_accept
-        self.swap_propose = state.swap_propose
-        return eng.set_slot_betas(carry, slots, state.betas)
+            self.swap_rng = state.swap_rng
+            self.swap_accept = state.swap_accept
+            self.swap_propose = state.swap_propose
+            return eng.set_slot_betas(carry, slots, state.betas)
 
     def finalize(self, server, slots) -> JobResult:
         eng, m = server.engine, self.model_on(server)
-        spins = np.stack(
-            [eng.spins_flat(eng.extract_slot(server.carry, b))[0] for b in slots]
-        )
-        betas = np.asarray(server.carry.betas)[eng.phys_slots(slots)]
+        with server.telemetry.span("engine.extract", cat="engine"):
+            spins = np.stack(
+                [eng.spins_flat(eng.extract_slot(server.carry, b))[0] for b in slots]
+            )
+            betas = np.asarray(server.carry.betas)[eng.phys_slots(slots)]
         return JobResult(
             jid=self.jid,
             spins=spins,

@@ -1020,6 +1020,7 @@ class SampleServer:
         )
         self.telemetry.name_thread(0, "scheduler")
         tel = self.telemetry
+        self.engine.telemetry = tel  # the engine's spans join the server's
         self._c_launches = tel.counter("serve.launches")
         # the global sweep clock (sum of chunks), read via .sweeps_elapsed
         self._c_sweeps = tel.counter("serve.sweeps_elapsed")
@@ -1279,22 +1280,28 @@ class SampleServer:
                 affine=affine,
                 mode=self._pool.mode,
             )
+        tel = self.telemetry
         if job.parked is not None:
             model = job.model_on(self) if self.multi_tenant else None
-            for b, parked in zip(taken, job.parked):
-                self.carry = self.engine.slot(b).resume(
-                    self.carry, parked, model=model
-                )
+            with tel.span("engine.splice", cat="engine"):
+                for b, parked in zip(taken, job.parked):
+                    self.carry = self.engine.slot(b).resume(
+                        self.carry, parked, model=model
+                    )
             job.parked = None
         else:
-            for b, slot_carry in zip(taken, job.init_carries(self)):
-                if self.multi_tenant:
-                    # The slot sweeps the job's model from now on: splice
-                    # its coupling tables next to the carry (jobs without a
-                    # model reset the slot to the base model, so a retired
-                    # tenant's tables never leak into the next job).
-                    self.engine.set_slot_model(b, job.model_on(self))
-                self.carry = self.engine.slot(b).splice(self.carry, slot_carry)
+            with tel.span("job.init"):
+                slot_carries = job.init_carries(self)
+            with tel.span("engine.splice", cat="engine"):
+                for b, slot_carry in zip(taken, slot_carries):
+                    if self.multi_tenant:
+                        # The slot sweeps the job's model from now on:
+                        # splice its coupling tables next to the carry
+                        # (jobs without a model reset the slot to the base
+                        # model, so a retired tenant's tables never leak
+                        # into the next job).
+                        self.engine.set_slot_model(b, job.model_on(self))
+                    self.carry = self.engine.slot(b).splice(self.carry, slot_carry)
         if job._admit_time is None:
             job._admit_time = time.perf_counter()
             job._admit_sweep = self.sweeps_elapsed
@@ -1468,7 +1475,8 @@ class SampleServer:
         else:
             compiled = chunk in self._warm_chunks
             t0 = time.perf_counter()
-            self.carry = self.engine.run(self.carry, chunk)
+            with tel.span("engine.dispatch", cat="engine"):
+                self.carry = self.engine.run(self.carry, chunk)
             pending = (t0, compiled)
         self._warm_chunks.add(chunk)
         self._c_launches.add(1)
@@ -1492,7 +1500,8 @@ class SampleServer:
         if pending is not None:
             t0, compiled = pending
             if self._skew is not None and tel.enabled:
-                times = self.engine.device_ready_times(self.carry, t0)
+                with tel.span("engine.wait", cat="engine"):
+                    times = self.engine.device_ready_times(self.carry, t0)
                 dt = float(times.max())
                 flagged = self._skew.record(times)
                 if flagged:
@@ -1504,7 +1513,8 @@ class SampleServer:
                         times_s=[float(t) for t in times],
                     )
             else:
-                jax.block_until_ready(self.carry)
+                with tel.span("engine.wait", cat="engine"):
+                    jax.block_until_ready(self.carry)
                 dt = time.perf_counter() - t0
             if self._chunker is not None:
                 self._chunker.observe(chunk, dt)
@@ -1585,7 +1595,8 @@ class SampleServer:
                 job, taken = self._active[jid]
                 self.carry = job.on_segment(self, self.carry, taken)
                 if job.done:
-                    completed.append(job.finalize(self, taken))
+                    with tel.span("job.finalize"):
+                        completed.append(job.finalize(self, taken))
                     self._pool.release_all(taken)  # raises on double-free
                     del self._active[jid]
                     self._retired.append(jid)

@@ -8,7 +8,9 @@ bounded with visible drop accounting; and `stats()` reads the SAME
 registry the exporters scrape, so their numbers cannot disagree.
 """
 
+import glob
 import json
+import os
 
 import jax
 import numpy as np
@@ -120,6 +122,36 @@ def test_span_nesting_enforced():
     validate_events(tel.events())
 
 
+def _xplane_host_lines(logdir) -> list:
+    """The events of each thread line of a profiler trace's ``/host:CPU``
+    plane, as ``[(name, start_ns, end_ns), ...]`` per line."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"), recursive=True)
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:CPU"):
+            for ln in plane.lines:
+                lines.append([(ev.name, int(ev.start_ns), int(ev.start_ns + ev.duration_ns))
+                              for ev in ln.events])
+    return lines
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_span_is_a_profiler_annotation_only_when_enabled(tmp_path, enabled):
+    tel = Telemetry(enabled=enabled)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tel.span("obs.outer"):
+            with tel.span("obs.inner"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    names = {n for line in _xplane_host_lines(tmp_path) for n, _, _ in line}
+    assert names & {"obs.outer", "obs.inner"} == ({"obs.outer", "obs.inner"} if enabled else set())
+    assert tel.num_events == (4 if enabled else 0)
+
+
 def test_disabled_telemetry_keeps_counting():
     """enabled=False silences events only: stats()/exporters still need
     the metrics, so counters keep counting."""
@@ -221,6 +253,75 @@ def test_results_identical_with_telemetry_on_off(rung, backend):
         np.testing.assert_array_equal(a.energy, b.energy)
     for a, b in zip(off, tapped):
         np.testing.assert_array_equal(a.spins, b.spins)
+
+
+# The spans the program writes inside a scheduling step, below
+# ``sched.step``: the host's work of the served path, one name each.
+PROGRAM_SPANS = (
+    "engine.dispatch", "engine.wait", "pt.hook", "pt.gather", "pt.swap",
+    "anneal.hook", "engine.set_betas", "job.finalize", "engine.extract",
+    "job.init", "engine.splice",
+)
+
+
+def _hooked_jobs():
+    """A PT ladder and anneal ramps: admission, both hooks, retirement."""
+    jobs = [AnnealJob.ramp(seed=s, beta_start=0.5, beta_end=1.5, steps=3,
+                           sweeps_per_step=4) for s in (10, 11, 12)]
+    jobs.append(PTJob(seed=9, betas=np.linspace(0.5, 1.5, 2), num_rounds=3,
+                      sweeps_per_round=4))
+    return jobs
+
+
+def _inside(outer, inner) -> bool:
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def test_program_spans_land_in_the_profiler_trace_nested(tmp_path):
+    """Under a `jax.profiler` trace every span of `PROGRAM_SPANS` is in
+    the host plane, inside a ``sched.step`` on the same thread line, with
+    the hooks' and retirement's parts inside their parents -- and the
+    results stay those of a telemetry-off server."""
+    off = _server(telemetry=False, policy="fifo")
+    on = _server(policy="fifo")
+    for srv in (off, on):
+        for job in _hooked_jobs():
+            srv.submit(job)
+    expect = sorted(off.drain(), key=lambda r: r.jid)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        got = sorted(on.drain(), key=lambda r: r.jid)
+    finally:
+        jax.profiler.stop_trace()
+    assert len(got) == len(expect) == 4
+    for a, b in zip(expect, got):
+        np.testing.assert_array_equal(a.spins, b.spins)
+        np.testing.assert_array_equal(a.energy, b.energy)
+
+    names = PROGRAM_SPANS
+    assert len(names) == len(set(names)) == 11
+    seen = set()
+    for line in _xplane_host_lines(tmp_path):
+        steps = [ev for ev in line if ev[0] == "sched.step"]
+        by_name = {}
+        for ev in line:
+            by_name.setdefault(ev[0], []).append(ev)
+        for ev in line:
+            if ev[0] in names:
+                seen.add(ev[0])
+                assert any(_inside(s, ev) for s in steps), ev
+        for parent, children in (("pt.hook", ("pt.gather", "pt.swap")),
+                                 ("job.finalize", ("engine.extract",))):
+            for child in children:
+                for ev in by_name.get(child, []):
+                    assert any(_inside(p, ev) for p in by_name[parent]), ev
+        hooks = by_name.get("pt.hook", []) + by_name.get("anneal.hook", [])
+        for ev in by_name.get("engine.set_betas", []):
+            assert any(_inside(h, ev) for h in hooks), ev
+        in_pt = [ev for ev in by_name.get("engine.set_betas", [])
+                 if any(_inside(h, ev) for h in by_name.get("pt.hook", []))]
+        assert len(in_pt) == len(by_name.get("pt.hook", []))
+    assert seen == set(names)
 
 
 @pytest.mark.skipif(
