@@ -20,11 +20,12 @@ indexed one 624-entry block modulo 624, which silently reused (and thus
 correlated) pair uniforms whenever R > 2*624.
 
 The pieces are deliberately separable: `swap_phase` (jitted, operates on
-a PTState) and `energy_tables` are public so the serving layer can express
-a whole tempering workload as one multi-slot job — `serve_mc.PTJob` packs
-its R replicas into R slots of the shared resident engine, and a
-tempering round becomes "one scheduled chunk + this swap_phase", sharing
-fused launches with whatever else is resident (see DESIGN.md §Service).
+a PTState), its in-batch form `swap_slots` and `energy_tables` are public
+so the serving layer can express a whole tempering workload as one
+multi-slot job — `serve_mc.PTJob` packs its R replicas into R slots of
+the shared resident engine, and a tempering round becomes "one scheduled
+chunk + one `swap_slots` call", sharing fused launches with whatever else
+is resident (see DESIGN.md §Service).
 """
 
 from __future__ import annotations
@@ -173,11 +174,11 @@ def _swap_decide(
     swap_parity: jax.Array,
     exp_fn,
 ):
-    """The swap decision given per-replica energies — the single body both
-    `swap_phase` (which computes energies itself) and
+    """The swap decision given per-replica energies — the single body that
+    `swap_phase` and `swap_slots` (which compute energies themselves) and
     `swap_phase_from_energies` (which receives them, e.g. gathered from a
-    mesh-sharded engine) run, so the two entry points are bit-identical by
-    construction.  Returns (betas, swap_rng, swap_accept, swap_propose)."""
+    mesh-sharded engine) run, so the three entry points are bit-identical
+    by construction.  Returns (betas, swap_rng, swap_accept, swap_propose)."""
     R = betas.shape[0]
     swap_rng, su = draw_swap_uniforms(swap_rng, R)
     # Propose swaps between (i, i+1) for i of the given parity.
@@ -223,6 +224,39 @@ def swap_phase(
     return state._replace(
         betas=betas, swap_rng=swap_rng, swap_accept=acc, swap_propose=prop
     )
+
+
+@functools.partial(jax.jit, static_argnames=("n", "exp_flavor"))
+def swap_slots(
+    spins: jax.Array,  # (B, rows, V) the whole batch's spins
+    betas: jax.Array,  # (B,) the whole batch's betas row
+    idx: jax.Array,  # (R,) int32 batch rows of the ladder, in replica order
+    swap_rng: jax.Array,
+    swap_accept: jax.Array,
+    swap_propose: jax.Array,
+    swap_parity: jax.Array,
+    base_nbr: jax.Array,
+    base_J: jax.Array,  # (n, SD) NOT doubled
+    tau_J: jax.Array,  # (n,)
+    h: jax.Array,
+    n: int,
+    exp_flavor: str = "fast",
+):
+    """`swap_phase` for a ladder that lives in rows ``idx`` of a larger
+    batch (a `serve_mc.PTJob` in the shared carry): one call gathers only
+    the ladder's spins, computes their energies with `swap_phase`'s
+    expression, decides the exchanges, and writes the new betas into the
+    batch's row.  ``idx`` and the parity are traced, so every ladder of
+    one size shares one executable wherever it sits.  Returns
+    ``(betas row, swap_rng, swap_accept, swap_propose)``."""
+    energies = jax.vmap(
+        lambda s: lane_energy(s, h, base_nbr, base_J, tau_J, n)
+    )(spins[idx])
+    new, swap_rng, acc, prop = _swap_decide(
+        betas[idx], energies, swap_rng, swap_accept, swap_propose,
+        swap_parity, EXP_FNS[exp_flavor],
+    )
+    return betas.at[idx].set(new), swap_rng, acc, prop
 
 
 @functools.partial(jax.jit, static_argnames=("exp_flavor",))

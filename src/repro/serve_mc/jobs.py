@@ -12,10 +12,10 @@ boundaries, where the job's ``on_segment`` hook runs:
   * `AnnealJob`   — one slot; a piecewise-constant anneal schedule.  The
     hook rewrites the slot's beta to the next segment's value.
   * `PTJob`       — R slots; every segment is one parallel-tempering
-    round.  The hook is `tempering.swap_phase` over the job's own slots
-    (gathered out of the shared carry), so a tempering round is literally
-    "one scheduled chunk + swap" and shares fused launches with whatever
-    else is resident.
+    round.  The hook is one `tempering.swap_slots` call over the shared
+    carry: it reads only the job's spins and betas and writes the swapped
+    betas back, so a tempering round is literally "one scheduled chunk +
+    swap" and shares fused launches with whatever else is resident.
 
 Both job types reproduce their standalone counterparts bit for bit: an
 `AnnealJob` equals a solo ``SweepEngine`` run with the same seed and
@@ -35,8 +35,11 @@ from __future__ import annotations
 import time
 from typing import NamedTuple, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core import engine as sweep_engine
 from repro.core import ising, mt19937, observables, tempering
@@ -383,10 +386,11 @@ class PTJob(_ScheduledJob):
     """A whole parallel-tempering workload as ONE multi-slot job.
 
     Occupies R slots (one per replica).  Every segment is one PT round of
-    ``sweeps_per_round`` sweeps; at each boundary the job gathers its
-    slots into a `tempering.PTState` and runs the same jitted
-    `tempering.swap_phase` the standalone driver uses, then scatters the
-    swapped betas back into the shared carry.  Seeding reproduces
+    ``sweeps_per_round`` sweeps; at each boundary the job makes one
+    `tempering.swap_slots` call on the shared carry, which reads its
+    slots' spins and betas, decides the swaps with the standalone driver's
+    energies and decision body, and writes the swapped betas back in the
+    same call.  Seeding reproduces
     `tempering.init_pt` exactly (replica b gets RNG lane seeds
     ``lane_seeds(R, V, seed)[b*V:(b+1)*V]`` and spins
     ``init_spins(m, seed*1000 + b)``), so the result is bit-identical to
@@ -418,14 +422,15 @@ class PTJob(_ScheduledJob):
         self.swap_accept = jnp.int32(0)
         self.swap_propose = jnp.int32(0)
         self._energy_tables = None  # built on first swap for a private model
+        self._rows = None  # (slots, device array of their carry rows)
 
     def snapshot_state(self) -> tuple[dict, dict]:
         meta, arrays = self._snapshot_base()
         meta["seed"] = self.seed
         meta["sweeps_per_round"] = self._segments[0]
         # The swap decision stream: generator columns at their exact
-        # position plus the accept/propose tallies.  `_energy_tables` is a
-        # pure cache — rebuilt from the model on first post-restore swap,
+        # position plus the accept/propose tallies.  `_energy_tables` and
+        # `_rows` are pure caches — rebuilt on first post-restore swap,
         # bit-identically (float32 arrays round-trip exactly).
         meta["swap_accept"] = int(self.swap_accept)
         meta["swap_propose"] = int(self.swap_propose)
@@ -467,22 +472,20 @@ class PTJob(_ScheduledJob):
             for b in range(self.num_slots)
         ]
 
-    def _gather_state(self, eng, carry, slots) -> tempering.PTState:
-        # Physical carry rows of the ladder's LOGICAL slots (identity
-        # unless the engine pads an uneven capacity vector).
-        idx = eng.phys_slots(slots)
-        lanes = eng._slot_lanes()
-        cols = np.concatenate([np.arange(b * lanes, (b + 1) * lanes) for b in idx])
-        return tempering.PTState(
-            carry.spins[idx],
-            carry.h_space[idx],
-            carry.h_tau[idx],
-            carry.betas[idx],
-            carry.rng[:, cols],
-            swap_rng=self.swap_rng,
-            swap_accept=self.swap_accept,
-            swap_propose=self.swap_propose,
-        )
+    def _slot_rows(self, eng, slots):
+        """The ladder's physical carry rows (identity unless the engine
+        pads an uneven capacity vector) as an int32 device array, built
+        once per placement: a park and resume or a rebalance moves the
+        job to other slots, and only then is it rebuilt."""
+        key = tuple(int(b) for b in slots)
+        if self._rows is None or self._rows[0] != key:
+            rows = eng.phys_slots(key).astype(np.int32)
+            if eng.mesh is None:
+                self._rows = (key, jnp.asarray(rows))
+            else:  # replicated beside the sharded carry
+                self._rows = (key, jax.device_put(
+                    rows, NamedSharding(eng.mesh, P())))
+        return self._rows[1]
 
     def _swap_energy_tables(self, eng):
         """Energy tables of the job's model: the engine's when the job has
@@ -502,16 +505,14 @@ class PTJob(_ScheduledJob):
             # Placement-aware routing: the cross-device energy gather is only
             # needed when the ladder actually SPANS devices.  A device-local
             # placement (what affine admission produces) takes the same
-            # in-device `swap_phase` fast path as an unsharded server — its
-            # slot gather touches one device's shard only.  Both paths share
-            # `_swap_decide`, so routing by placement is bit-invisible
-            # (tests/test_placement.py).
+            # one-call `swap_slots` path as an unsharded server.  Both paths
+            # share `_swap_decide` and `lane_energy`, so routing by placement
+            # is bit-invisible (tests/test_placement.py).
             spans = (
                 eng.mesh is not None
                 and len({eng.slot_device(b) for b in slots}) > 1
             )
-            if eng.mesh is not None:
-                (server._c_swap_cross if spans else server._c_swap_local).add(1)
+            (server._c_swap_cross if spans else server._c_swap_local).add(1)
             if spans:
                 # Cross-device path: a ladder spanning devices must NOT gather
                 # its slots' spins (that is the whole carry).  Each device
@@ -537,20 +538,28 @@ class PTJob(_ScheduledJob):
                         )
                     )
                 return eng.set_slot_betas(carry, slots, betas)
-            with tel.span("pt.gather"):
-                state = self._gather_state(eng, carry, slots)
+            # Resident path: one device call reads the ladder's spins and
+            # betas out of the carry and writes the swapped betas back.
             with tel.span("pt.swap"):
-                state = tempering.swap_phase(
-                    state,
-                    *self._swap_energy_tables(eng),
-                    jnp.asarray(parity, jnp.int32),
-                    eng.model.n,
-                    eng.exp_flavor,
+                betas, self.swap_rng, self.swap_accept, self.swap_propose = (
+                    tempering.swap_slots(
+                        carry.spins,
+                        carry.betas,
+                        self._slot_rows(eng, slots),
+                        self.swap_rng,
+                        self.swap_accept,
+                        self.swap_propose,
+                        np.int32(parity),
+                        *self._swap_energy_tables(eng),
+                        eng.model.n,
+                        eng.exp_flavor,
+                    )
                 )
-            self.swap_rng = state.swap_rng
-            self.swap_accept = state.swap_accept
-            self.swap_propose = state.swap_propose
-            return eng.set_slot_betas(carry, slots, state.betas)
+                if eng.mesh is not None:  # keep the betas row sharded
+                    betas = jax.device_put(
+                        betas, NamedSharding(eng.mesh, P("data"))
+                    )
+            return carry._replace(betas=betas)
 
     def finalize(self, server, slots) -> JobResult:
         eng, m = server.engine, self.model_on(server)

@@ -1033,7 +1033,8 @@ class SampleServer:
         self._h_wait = tel.histogram("serve.queue_wait_s")
         # Placement decisions and PT swap routing (DESIGN.md §Scheduling/
         # Placement): affine = all of a job's slots on one device;
-        # swap_local = a ladder's swap phase took the in-device fast path.
+        # every PT swap phase counts once, as swap_local (the one-call
+        # in-device path) or swap_cross (a ladder spanning devices).
         self._c_place_affine = tel.counter("sched.placements_affine")
         self._c_place_span = tel.counter("sched.placements_spanning")
         self._c_migrations = tel.counter("sched.rebalance_migrations")

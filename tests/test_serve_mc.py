@@ -113,10 +113,13 @@ def test_ramp_constructor():
 # -----------------------------------------------------------------------------
 
 
-def test_pt_job_equals_standalone_driver():
+@pytest.mark.parametrize("tenancy", ["single", "private"])
+def test_pt_job_equals_standalone_driver(tenancy):
     """A PTJob packed beside an anneal job whose segments do NOT align
     with PT rounds (rounds get split across chunks) must still reproduce
-    tempering.run_parallel_tempering bit for bit."""
+    tempering.run_parallel_tempering bit for bit -- on a single-model
+    server, and on a multi-tenant server whose base model is another
+    instance while the ladder carries its own."""
     m = ising.random_layered_model(n=4, L=8, seed=2, beta=1.0)
     betas = np.linspace(0.4, 1.4, 4).astype(np.float32)
     rounds, spr = 3, 2
@@ -126,10 +129,18 @@ def test_pt_job_equals_standalone_driver():
     solo_spins = np.stack(
         [reorder.from_lane(np.asarray(s), m.n, m.L, 4) for s in state.spins]
     )
-    srv = SampleServer(m, slots=6, chunk_sweeps=4, rung="a4", backend="jnp", V=4)
+    if tenancy == "single":
+        srv = SampleServer(m, slots=6, chunk_sweeps=4, rung="a4",
+                           backend="jnp", V=4)
+        pt = PTJob(seed=5, betas=betas, num_rounds=rounds, sweeps_per_round=spr)
+    else:
+        base = ising.reseed_couplings(m, seed=41, beta=0.9)
+        srv = SampleServer(base, slots=6, chunk_sweeps=4, rung="a4",
+                           backend="jnp", V=4, multi_tenant=True)
+        pt = PTJob(seed=5, betas=betas, num_rounds=rounds, sweeps_per_round=spr,
+                   model=m)
     # Budget 5 forces chunk sizes 2,2,1,... -> PT rounds split mid-round.
     srv.submit(AnnealJob.constant(seed=99, sweeps=5, beta=0.8))
-    pt = PTJob(seed=5, betas=betas, num_rounds=rounds, sweeps_per_round=spr)
     srv.submit(pt)
     res = {r.jid: r for r in srv.drain()}
     r = res[pt.jid]
@@ -138,6 +149,29 @@ def test_pt_job_equals_standalone_driver():
     np.testing.assert_allclose(r.energy, energies, rtol=1e-5)
     assert r.extras["swap_propose"] == int(state.swap_propose)
     assert r.extras["swap_accept"] == int(state.swap_accept)
+    np.testing.assert_array_equal(np.asarray(pt.swap_rng),
+                                  np.asarray(state.swap_rng))
+
+
+def test_pt_swaps_share_one_executable_across_slots():
+    """Two ladders of one size on different slots, through rounds of both
+    parities: every swap phase is one `tempering.swap_slots` call and all
+    of them run one executable; each is counted as a local swap on an
+    unsharded server."""
+    m = ising.random_layered_model(n=4, L=8, seed=6, beta=1.0)
+    srv = SampleServer(m, slots=7, chunk_sweeps=2, rung="a4", backend="jnp",
+                       V=4)
+    betas = np.linspace(0.5, 1.5, 3).astype(np.float32)
+    jobs = [PTJob(seed=s, betas=betas, num_rounds=4, sweeps_per_round=2)
+            for s in (3, 4)]
+    for job in jobs:
+        srv.submit(job)
+    tempering.swap_slots.clear_cache()
+    srv.drain()
+    assert tempering.swap_slots._cache_size() == 1
+    assert [job._rows[0] for job in jobs] == [(0, 1, 2), (3, 4, 5)]
+    place = srv.stats()["placement"]
+    assert place["pt_swap_local"] == 8 and place["pt_swap_cross"] == 0
 
 
 def test_pt_job_waits_for_enough_free_slots():

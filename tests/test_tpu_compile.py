@@ -152,3 +152,23 @@ def test_cb_engine_step_sharded_over_four_chips_compiles(topo, model, backend):
     for op in ("all-gather", "all-reduce", "collective-permute", "all-to-all"):
         assert op not in text, op
     assert compiled.output_shardings[0].spec == P("data", None, None)
+
+
+def test_pt_swap_slots_compiles_for_v5e(one_chip, model):
+    """The served PT swap phase: one call over the whole carry's spins and
+    betas for a ladder in half the slots."""
+    from repro.core import tempering
+
+    eng = SweepEngine.create(model, rung="cb", backend="jnp", batch=SLOTS, V=LANES)
+    rows, R = eng.tables["rows"], SLOTS // 2
+    tables = [_shape(one_chip, t.shape, t.dtype)
+              for t in tempering.energy_tables(eng)]
+    scalar = _shape(one_chip, (), jnp.int32)
+    compiled = tempering.swap_slots.lower(
+        _shape(one_chip, (SLOTS, rows, LANES)), _shape(one_chip, (SLOTS,)),
+        _shape(one_chip, (R,), jnp.int32), _shape(one_chip, (mt.N,), jnp.uint32),
+        scalar, scalar, scalar, *tables, n=N, exp_flavor=eng.exp_flavor,
+    ).compile()
+    betas, swap_rng, acc, prop = compiled.out_info
+    assert betas.shape == (SLOTS,) and swap_rng.shape == (mt.N,)
+    assert acc.shape == prop.shape == ()
