@@ -4,7 +4,9 @@ window on its served path, then check what the window produced.
 The program under test is the sampling service (`repro.serve_mc`):
 `SampleServer.submit` / `step` with the configuration's `ServeConfig`
 fields.  Everything else here (instances, traffic, timing, reference,
-comparison) belongs to the benchmark.
+comparison) belongs to the benchmark.  What depends on the kind of job
+the mix sends (its specs, the program's job, the outputs at the close
+and their replay) is the kind's module, ``bench/kinds/<kind>.py``.
 """
 
 from __future__ import annotations
@@ -16,7 +18,7 @@ import time
 import jax
 import numpy as np
 
-from harness import check, models, reference, traffic
+from harness import check, models, reference, spec as spec_mod
 
 TRACE_SECONDS = 3.0  # length of the profiled stretch of a traced window
 DRAIN_GRACE_S = 120.0  # how long an open loop waits past the window for due jobs
@@ -49,11 +51,16 @@ class CompileCounter:
         if name.endswith("backend_compile_duration") and self.armed:
             self.counts["backend_window"] += 1
 
+    def arm(self) -> None:
+        """Count compiles from now on, afresh: a run's window."""
+        self.counts["requests_window"] = self.counts["backend_window"] = 0
+        self.armed = True
+
 
 class Served:
     """The system under test, with the bench's view of its jobs."""
 
-    def __init__(self, cfg: dict, chips: int, insts: list):
+    def __init__(self, cfg: dict, chips: int, insts: list, kind):
         from repro.core import ising
         from repro.serve_mc import SampleServer, ServeConfig
 
@@ -69,19 +76,11 @@ class Served:
             fields["mesh"] = make_slot_mesh(chips)
         self.server = SampleServer(self.models[0], config=ServeConfig(**fields))
         self.multi = bool(fields.get("multi_tenant"))
+        self.kind = kind
         self.jobs = {}  # jid -> (spec, job)
 
-    def make_job(self, spec: dict):
-        from repro.serve_mc import AnnealJob, PTJob
-
-        if spec["kind"] == "pt":
-            return PTJob(spec["seed"], spec["betas"], num_rounds=spec["rounds"],
-                         sweeps_per_round=spec["sweeps_per_round"])
-        model = self.models[spec["instance"]] if self.multi else None
-        return AnnealJob(spec["seed"], spec["schedule"], model=model, user=spec["user"])
-
     def submit(self, spec: dict):
-        job = self.make_job(spec)
+        job = self.kind.make_job(self, spec)
         with _annotate("bench.submit"):
             self.server.submit(job)
         self.jobs[job.jid] = (spec, job)
@@ -105,13 +104,14 @@ class Loop:
         self.retired = {}  # jid -> (result, t_retired)
         self.lateness = []
         self.t0 = None
-        if mix["kind"] == "pt_ladders":
+        self.pending = collections.deque()  # an open loop's next spec
+        specs = served.kind.specs(mix, chips, seed, len(served.insts))
+        if isinstance(specs, list):  # a closed, fixed set of jobs
             self.stream = None
-            for spec in traffic.ladder_specs(mix, chips, seed):
+            for spec in specs:
                 served.submit(spec)
         else:
-            self.stream = traffic.AnnealStream(mix, len(served.insts), seed)
-            self.pending = collections.deque()
+            self.stream = specs
 
     def start(self) -> None:
         self.t0 = time.perf_counter()
@@ -160,8 +160,9 @@ def run(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float, trace: bool
         t_process: float, counter: CompileCounter, trace_dir: str | None = None) -> dict:
     """One whole run; returns the raw record the reporting step reads."""
     chips = cell["chips"]
+    kind = spec_mod.kind(mix["kind"])
     insts = models.instances(cfg, seed)
-    served = Served(cfg, chips, insts)
+    served = Served(cfg, chips, insts, kind)
     srv = served.server
     loop = Loop(served, mix, chips, seed)
     spins_per_replica = insts[0]["n"] * insts[0]["L"]
@@ -177,7 +178,7 @@ def run(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float, trace: bool
     before = loop.progress()
     busy0 = srv.busy_slot_sweeps
     retired0 = set(loop.retired)
-    counter.armed = True
+    counter.arm()
     tracing = None
     if trace:
         from harness import tracing as tr
@@ -229,20 +230,19 @@ def run(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float, trace: bool
     rec["memory_peak_bytes"] = max(
         (d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in jax.devices()[:chips])
     if tracing is not None:
+        rec["trace_record"] = tracing.record
         rec["trace"] = tracing.reduce()
 
     # What the window produced, for the check.
     rng = np.random.default_rng([seed, 3])
+    pool = [j for j in (rec["due"] if loop.open else rec["retired_window"]) if j in loop.retired]
+    outputs = kind.outputs(served, loop, pool, mix, rng)
     if loop.stream is None:
-        outputs = _ladder_outputs(served)
         rec["attempted"], rec["failed"] = len(served.jobs), 0
+    elif loop.open:
+        rec["attempted"], rec["failed"] = len(rec["due"]), len(rec["lost"])
     else:
-        pool = [j for j in (rec["due"] if loop.open else rec["retired_window"]) if j in loop.retired]
-        outputs = _anneal_outputs(served, loop, pool, mix, rng)
-        if loop.open:
-            rec["attempted"], rec["failed"] = len(rec["due"]), len(rec["lost"])
-        else:
-            rec["attempted"], rec["failed"] = len(rec["retired_window"]), 0
+        rec["attempted"], rec["failed"] = len(rec["retired_window"]), 0
     lattice = (insts[0]["n"], insts[0]["L"], srv.engine.V, insts[0]["space_nbr"])
     lost = len(rec.get("lost", []))
     del loop, served, srv
@@ -250,7 +250,7 @@ def run(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float, trace: bool
 
     # The reference runs only now, with the program's state freed.
     t_ref = time.perf_counter()
-    rec["checks"] = check.compare(outputs, reference.Lattice(*lattice), insts, lost)
+    rec["checks"] = check.compare(outputs, reference.Lattice(*lattice), insts, lost, kind)
     rec["reference_s"] = time.perf_counter() - t_ref
     return rec
 
@@ -265,51 +265,3 @@ def _fifths(ticks: list, window_s: float) -> list:
         out.append(upto - last)
         last = upto
     return out
-
-
-def _ladder_outputs(served: Served) -> dict:
-    """Every ladder's state at the close: each holds a block of slots, so a
-    fault in any part of the batch shows in some ladder."""
-    srv = served.server
-    eng = srv.engine
-    out = []
-    for spec, job in served.jobs.values():
-        slots = served.slots_of(job)
-        idx = eng.phys_slots(slots)
-        V = eng.V
-        cols = np.concatenate([np.arange(b * V, (b + 1) * V) for b in idx])
-        result = job.finalize(srv, slots)
-        out.append(dict(
-            kind="pt", seed=spec["seed"], betas0=spec["betas"],
-            sweeps=job.sweeps_done, sweeps_per_round=spec["sweeps_per_round"],
-            spins=np.asarray(srv.carry.spins[idx]), h_space=np.asarray(srv.carry.h_space[idx]),
-            h_tau=np.asarray(srv.carry.h_tau[idx]), rng=np.asarray(srv.carry.rng[:, cols]),
-            betas=np.asarray(srv.carry.betas[idx]), energy=np.asarray(result.energy),
-            spins_flat=np.asarray(result.spins), swap_accept=int(job.swap_accept),
-            swap_propose=int(job.swap_propose)))
-    return dict(ladders=out)
-
-
-def _anneal_outputs(served: Served, loop: Loop, pool: list, mix: dict, rng) -> dict:
-    srv = served.server
-    eng = srv.engine
-    done = []
-    for jid in sorted(rng.choice(pool, size=min(mix["sample_jobs"], len(pool)), replace=False)):
-        spec, job = served.jobs[int(jid)]
-        r = loop.retired[int(jid)][0]
-        done.append(dict(spec=spec, sweeps=job.sweeps_done, spins_flat=np.asarray(r.spins),
-                         energy=float(r.energy), beta=np.float32(r.extras["final_beta"])))
-    active = [job for _, job in served.jobs.values()
-              if job.jid in srv._active and job.sweeps_done > 0]
-    live = []
-    picks = rng.choice(len(active), size=min(mix["sample_active"], len(active)), replace=False)
-    for k in sorted(picks):
-        job = active[k]
-        spec = served.jobs[job.jid][0]
-        b = int(eng.phys_slots(served.slots_of(job))[0])
-        V = eng.V
-        live.append(dict(spec=spec, sweeps=job.sweeps_done,
-                         spins=np.asarray(srv.carry.spins[b]), h_space=np.asarray(srv.carry.h_space[b]),
-                         h_tau=np.asarray(srv.carry.h_tau[b]), rng=np.asarray(srv.carry.rng[:, b * V:(b + 1) * V]),
-                         beta=np.asarray(srv.carry.betas[b])))
-    return dict(done=done, live=live)

@@ -1,8 +1,9 @@
 """The plain reference of the served computation, written from the
 algorithm's definition and importing nothing of the program.
 
-What it computes, for one or many replicas of a layered QMC Ising model
-(arXiv:1004.0024 §2-§4, the graph-colored "cb" sweep order):
+The parts every job kind shares, for one or many replicas of a layered
+QMC Ising model (arXiv:1004.0024 §2-§4, the graph-colored "cb" sweep
+order); each kind's replay (``bench/kinds/<kind>.py``) is built on them:
 
 * Layout: layer ``l`` of site ``i`` lives at row ``(l mod L/V) * n + i``,
   lane ``l div L/V`` of a (rows, V) tile.
@@ -17,10 +18,8 @@ What it computes, for one or many replicas of a layered QMC Ising model
   outputs, as 24-bit uniforms.
 * Flip: accept when ``u < exp(-2 beta s h)``, with the exponential
   computed by the bit trick of the paper's §2.4 ("fast" flavour).
-* Parallel tempering: every 16 sweeps the adjacent pairs of one parity
-  propose a beta exchange with probability ``min(1, exp(dbeta * dE))``,
-  energies summed in one fixed pairwise order, one uniform per pair from
-  a scalar MT19937 seeded ``seed + 17``.
+* Fields and energies: the carried effective fields of a configuration,
+  and its energy in float64.
 
 Every float operation is written in the order the algorithm states it,
 so a sound program reproduces the reference bit for bit.  ``ftype``
@@ -160,7 +159,7 @@ def _temper(y):
     return y ^ (y >> np.uint32(18))
 
 
-def _uniforms(mt, count: int):
+def uniforms(mt, count: int):
     """``count`` uniforms per column from ceil(count/624) fresh blocks."""
     outs = []
     for _ in range(-(-count // MT_N)):
@@ -206,12 +205,12 @@ def _sweep(lat: Lattice, ftype, spins, u, betas, h, J, tau):
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def _run_sweeps(lat: Lattice, ftype, num: int, spins, mt, betas, h, J, tau):
+def run_sweeps(lat: Lattice, ftype, num: int, spins, mt, betas, h, J, tau):
     B, rows, V = spins.shape
 
     def body(_, carry):
         spins, mt = carry
-        mt, u = _uniforms(mt, rows)
+        mt, u = uniforms(mt, rows)
         u = u.reshape(rows, B, V).transpose(1, 0, 2)
         return _sweep(lat, ftype, spins, u, betas, h, J, tau), mt
 
@@ -232,47 +231,6 @@ def fields(lat: Lattice, spins, h, J, tau, ftype=f32):
     return hs.reshape(shape).astype(f32), ht.reshape(shape).astype(f32)
 
 
-def _pairwise(x):
-    x = jnp.ravel(x)
-    size = 1 << max(x.size - 1, 0).bit_length()
-    x = jnp.pad(x, (0, size - x.size))
-    while x.size > 1:
-        x = x[: x.size // 2] + x[x.size // 2:]
-    return x[0]
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def swap_energies(lat: Lattice, ftype, spins, h, J, tau):
-    """(B,) energies of (B, rows, V) spins, one model, summed in a fixed
-    pairwise order (the tempering swap's energies)."""
-    def one(sp):
-        s = sp.reshape(lat.lpv, lat.n, lat.V).astype(ftype)
-        t = h[None, :, None].astype(ftype) * s
-        for d in range(lat.sd):
-            t = t + 0.5 * J[None, :, d, None].astype(ftype) * s * s[:, lat.nbr[:, d], :]
-        up = jnp.concatenate([s[1:], jnp.roll(s[:1], -1, axis=-1)], axis=0)
-        t = t + tau[None, :, None].astype(ftype) * s * up
-        return -_pairwise(t)
-    return jax.vmap(one)(spins).astype(f32)
-
-
-@jax.jit
-def _swap(betas, energies, swap_mt, parity):
-    R = betas.shape[0]
-    swap_mt, su = _uniforms(swap_mt[:, None], (R + 1) // 2)
-    swap_mt, su = swap_mt[:, 0], su[:, 0]
-    idx = jnp.arange(R)
-    left = (idx % 2 == parity) & (idx + 1 < R)
-    partner = jnp.clip(jnp.where(left, idx + 1, jnp.where(idx % 2 != parity, idx - 1, idx)), 0, R - 1)
-    valid = partner != idx
-    p_acc = fast_exp(jnp.clip((betas - betas[partner]) * (energies - energies[partner]), -20.0, 0.0))
-    u = su[idx // 2]
-    u = jnp.where(left, u, u[partner])
-    accept = valid & (u < p_acc)
-    return (jnp.where(accept, betas[partner], betas), swap_mt,
-            jnp.sum(accept.astype(jnp.int32)) // 2, jnp.sum((valid & left).astype(jnp.int32)))
-
-
 def energy_f64(model: dict, spins_flat: np.ndarray) -> np.ndarray:
     """Energy of flat (..., N) spins in float64: -sum h s - sum_edges J s s."""
     n, L = model["n"], model["L"]
@@ -291,91 +249,8 @@ def init_spins_flat(N: int, seed: int) -> np.ndarray:
     return np.where(rng.random(N) < 0.5, -1.0, 1.0).astype(np.float32)
 
 
-# -----------------------------------------------------------------------------
-# Replays.
-# -----------------------------------------------------------------------------
-
-
-def _tables(models: list):
+def tables(models: list):
+    """(h, J, tau) of the models, stacked, in float32."""
     return (jnp.asarray(np.stack([m["h"] for m in models]), f32),
             jnp.asarray(np.stack([m["space_J"] for m in models]), f32),
             jnp.asarray(np.stack([m["tau_J"] for m in models]), f32))
-
-
-def replay_anneals(lat: Lattice, jobs: list, unit: int = 8, ftype=f32) -> list:
-    """Replay single-slot annealing jobs from their seeds.
-
-    Each job is a dict with ``seed``, ``model`` (arrays), ``schedule``
-    (list of (sweeps, beta)) and ``sweeps`` (how many to run).  Returns,
-    per job, its final lane spins, fields, generator columns and beta.
-    """
-    n, L, V = lat.n, lat.L, lat.V
-    spins = jnp.asarray(np.stack([
-        to_lane(init_spins_flat(n * L, j["seed"] * 1000), n, L, V) for j in jobs]))
-    mt = jnp.asarray(np.concatenate([mt_seed(lane_seeds(V, j["seed"])) for j in jobs], axis=1))
-    h, J, tau = _tables([j["model"] for j in jobs])
-    units = [j["sweeps"] // unit for j in jobs]
-    if any(j["sweeps"] % unit for j in jobs):
-        raise ValueError("every replayed sweep count must be a multiple of the unit")
-    sched = []
-    for j in jobs:  # beta of each unit of sweeps
-        per = [b for s, b in j["schedule"] for _ in range(s // unit)]
-        sched.append(per + [per[-1]] * (max(units) - len(per)))
-    final = [None] * len(jobs)
-    for t in range(max(units)):
-        betas = jnp.asarray([np.float32(s[t]) for s in sched], f32)
-        spins, mt = _run_sweeps(lat, ftype, unit, spins, mt, betas, h, J, tau)
-        for k, u_k in enumerate(units):
-            if u_k == t + 1:
-                final[k] = (spins[k], mt[:, k * V:(k + 1) * V])
-    out = []
-    for k, j in enumerate(jobs):
-        sp, gen = final[k]
-        hs, ht = fields(lat, sp[None], h[k:k + 1], J[k:k + 1], tau[k:k + 1], ftype)
-        seg_end = np.cumsum([s for s, _ in j["schedule"]])
-        seg = min(int(np.searchsorted(seg_end, j["sweeps"], side="right")), len(seg_end) - 1)
-        out.append(dict(spins=np.asarray(sp), h_space=np.asarray(hs[0]),
-                        h_tau=np.asarray(ht[0]), rng=np.asarray(gen),
-                        beta=np.float32(j["schedule"][seg][1])))
-    return out
-
-
-def replay_ladders(lat: Lattice, model: dict, ladders: list, sweeps: int,
-                   sweeps_per_round: int, unit: int = 8, ftype=f32) -> list:
-    """Replay parallel-tempering ladders of one model from their seeds, all
-    in one batch.  ``ladders`` is a list of ``(seed, betas)``, every ladder
-    with the same number of rungs.  In a ladder seeded ``seed``, replica
-    ``b`` starts from the spins seeded ``seed * 1000 + b`` and the generator
-    columns ``lane_seeds(R * V, seed)[b * V:(b + 1) * V]``; a swap phase
-    follows every ``sweeps_per_round`` sweeps, parity alternating from 0,
-    with the ladder's own swap generator seeded ``seed + 17``."""
-    n, L, V = lat.n, lat.L, lat.V
-    K, R = len(ladders), len(ladders[0][1])
-    if any(len(b) != R for _, b in ladders):
-        raise ValueError("every ladder of a batch must have the same number of rungs")
-    spins = jnp.asarray(np.stack([
-        to_lane(init_spins_flat(n * L, seed * 1000 + b), n, L, V)
-        for seed, _ in ladders for b in range(R)]))
-    mt = jnp.asarray(np.concatenate([mt_seed(lane_seeds(R * V, seed)) for seed, _ in ladders],
-                                    axis=1))
-    swap_mt = jnp.asarray(np.stack([mt_seed(np.uint32(seed + 17)) for seed, _ in ladders]))
-    h1, J1, tau1 = _tables([model])
-    h, J, tau = (jnp.broadcast_to(x, (K * R,) + x.shape[1:]) for x in (h1, J1, tau1))
-    b = jnp.asarray(np.stack([np.asarray(betas, np.float32) for _, betas in ladders]))
-    swap = jax.vmap(_swap, in_axes=(0, 0, 0, None))
-    accepted = proposed = jnp.zeros((K,), jnp.int32)
-    per_round = sweeps_per_round // unit
-    for t in range(sweeps // unit):
-        spins, mt = _run_sweeps(lat, ftype, unit, spins, mt, b.reshape(-1), h, J, tau)
-        if (t + 1) % per_round == 0:
-            r = (t + 1) // per_round - 1
-            e = swap_energies(lat, ftype, spins, h1[0], J1[0], tau1[0]).reshape(K, R)
-            b, swap_mt, acc, prop = swap(b, e, swap_mt, jnp.int32(r % 2))
-            accepted, proposed = accepted + acc, proposed + prop
-    hs, ht = fields(lat, spins, h, J, tau, ftype)
-    spins, hs, ht, mt = (np.asarray(x) for x in (spins, hs, ht, mt))
-    b, accepted, proposed = np.asarray(b), np.asarray(accepted), np.asarray(proposed)
-    return [dict(spins=spins[k * R:(k + 1) * R], h_space=hs[k * R:(k + 1) * R],
-                 h_tau=ht[k * R:(k + 1) * R], rng=mt[:, k * R * V:(k + 1) * R * V],
-                 betas=b[k], swap_accept=int(accepted[k]), swap_propose=int(proposed[k]))
-            for k in range(K)]

@@ -9,10 +9,14 @@ recorded trace into a small normalized record:
      "host": [[name, start_ns, dur_ns], ...],
      "counters": {...}}
 
-``host`` holds the benchmark's own annotations and the program's
-telemetry spans placed on the profiler's clock.  `reduce` reads only
-this record, so the committed sample trace (``bench/trace_sample/``)
-checks the same arithmetic the runs use.
+``host`` holds, from the trace's host plane and on the profiler's own
+clock, the benchmark's annotations (``bench.*``) and every span of the
+program's telemetry (`Telemetry.span`, which is also a profiler
+annotation of its name) that the program opened or closed in the window.
+``counters`` holds the window's change of the server's three slot
+counters and of every counter of the program's telemetry.  `reduce`
+reads only this record, so the committed sample traces
+(``bench/trace_sample/``) check the same arithmetic the runs use.
 """
 
 from __future__ import annotations
@@ -20,24 +24,16 @@ from __future__ import annotations
 import bisect
 import glob
 import gzip
+import itertools
 import json
 import os
 import shutil
 import tempfile
-import time
 
 import jax
 
-HOST_SPANS = ("sched.admit", "sched.step", "engine.launch")
-# Where the host was, innermost first, for an idle gap at a given instant.
-GAP_LABELS = (
-    ("sched.admit", "scheduler: admission"),
-    ("engine.launch", "engine: launch dispatch to ready"),
-    ("sched.step", "scheduler: job hooks and retirement"),
-    ("bench.submit", "client: submit"),
-    ("bench.wait", "client: waiting for arrivals"),
-    ("bench.step", "client: step call"),
-)
+BENCH_PREFIX = "bench."  # the benchmark's own annotations
+WINDOW = "bench.window"  # also the label of idle time no other span covers
 
 
 class Window:
@@ -47,33 +43,36 @@ class Window:
         self.active = False
         self.record = None
 
+    def _counters(self) -> dict:
+        srv = self.server
+        return dict(srv.telemetry.metrics_snapshot()["counters"],
+                    busy_slot_sweeps=srv.busy_slot_sweeps,
+                    total_slot_sweeps=srv.total_slot_sweeps, launches=srv.launches)
+
     def start(self) -> None:
         srv = self.server
         jax.profiler.start_trace(self.dir)
-        self.ann = jax.profiler.TraceAnnotation("bench.window")
+        self.ann = jax.profiler.TraceAnnotation(WINDOW)
         self.ann.__enter__()
-        self.t0 = time.perf_counter()
         self.tel0 = srv.telemetry.now_us()
-        self.c0 = (srv.busy_slot_sweeps, srv.total_slot_sweeps, srv.launches)
+        self.c0 = self._counters()
         self.active = True
 
     def stop(self) -> None:
         srv = self.server
         jax.block_until_ready(srv.carry)
-        self.c1 = (srv.busy_slot_sweeps, srv.total_slot_sweeps, srv.launches)
-        self.tel1 = srv.telemetry.now_us()
+        c1 = self._counters()
+        tel1 = srv.telemetry.now_us()
         self.ann.__exit__(None, None, None)
         jax.profiler.stop_trace()
         self.active = False
-        events = srv.telemetry.events()
+        names = {ev["name"] for ev in srv.telemetry.events()
+                 if ev.get("ph") in ("B", "E") and self.tel0 <= ev["ts"] <= tel1}
         try:
-            self.record = normalize(self.dir, events, self.tel0, self.tel1)
+            self.record = normalize(self.dir, names)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
-        self.record["counters"] = dict(
-            busy_slot_sweeps=self.c1[0] - self.c0[0],
-            total_slot_sweeps=self.c1[1] - self.c0[1],
-            launches=self.c1[2] - self.c0[2])
+        self.record["counters"] = {k: v - self.c0.get(k, 0) for k, v in c1.items()}
 
     def reduce(self) -> dict:
         return reduce(self.record)
@@ -114,8 +113,10 @@ def _device_ops(lines: dict) -> list:
     return out
 
 
-def normalize(logdir: str, tel_events: list, tel0_us: float, tel1_us: float) -> dict:
-    """Read the newest xplane under ``logdir`` into the normalized record."""
+def normalize(logdir: str, program_spans) -> dict:
+    """Read the newest xplane under ``logdir`` into the normalized record,
+    keeping from the host plane the spans named in ``program_spans`` and
+    the benchmark's annotations."""
     from jax.profiler import ProfileData
 
     paths = sorted(glob.glob(os.path.join(logdir, "**", "*.xplane.pb"), recursive=True),
@@ -123,6 +124,7 @@ def normalize(logdir: str, tel_events: list, tel0_us: float, tel1_us: float) -> 
     if not paths:
         raise RuntimeError(f"no profiler trace was written under {logdir}")
     pd = ProfileData.from_file(paths[-1])
+    program_spans = set(program_spans)
     window, host, devices = None, [], {}
     for plane in pd.planes:
         if plane.name.startswith("/device:") and "CPU" not in plane.name:
@@ -132,29 +134,12 @@ def normalize(logdir: str, tel_events: list, tel0_us: float, tel1_us: float) -> 
         elif plane.name.startswith("/host:CPU"):
             for ln in plane.lines:
                 for ev in ln.events:
-                    if ev.name == "bench.window":
+                    if ev.name == WINDOW:
                         window = [int(ev.start_ns), int(ev.start_ns + ev.duration_ns)]
-                    elif ev.name.startswith("bench."):
+                    elif ev.name.startswith(BENCH_PREFIX) or ev.name in program_spans:
                         host.append([ev.name, int(ev.start_ns), int(ev.duration_ns)])
     if window is None:
-        raise RuntimeError("the trace holds no bench.window annotation")
-    # The program's telemetry spans, moved onto the profiler's clock: the
-    # window annotation opened at telemetry time tel0_us.
-    scale = (window[1] - window[0]) / max(1e-9, (tel1_us - tel0_us) * 1e3)
-    to_ns = lambda us: window[0] + (us - tel0_us) * 1e3 * min(1.0, scale)
-    opened = {}
-    for ev in tel_events:
-        name, ph = ev.get("name"), ev.get("ph")
-        if name not in HOST_SPANS:
-            continue
-        if ph == "B":
-            opened[name] = ev["ts"]
-        elif ph == "E" and name in opened:
-            t0 = opened.pop(name)
-            if t0 >= tel0_us and ev["ts"] <= tel1_us:
-                host.append([name, int(to_ns(t0)), int((ev["ts"] - t0) * 1e3)])
-        elif ph == "X" and ev["ts"] >= tel0_us and ev["ts"] + ev["dur"] <= tel1_us:
-            host.append([name, int(to_ns(ev["ts"])), int(ev["dur"] * 1e3)])
+        raise RuntimeError(f"the trace holds no {WINDOW} annotation")
     for evs in devices.values():
         evs[:] = [e for e in evs if e[1] + e[2] > window[0] and e[1] < window[1]]
     return dict(window=window, devices=devices, host=host)
@@ -170,30 +155,67 @@ def _union(intervals) -> list:
     return out
 
 
+def _overlap(a: list, b: list) -> int:
+    """Length of the intersection of two sorted lists of disjoint intervals."""
+    i = j = total = 0
+    while i < len(a) and j < len(b):
+        total += max(0, min(a[i][1], b[j][1]) - max(a[i][0], b[j][0]))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
+class _Innermost:
+    """Which of some host spans is the innermost that covers an instant:
+    of those that cover it, the one that opened last (a nested span opens
+    inside the span that holds it)."""
+
+    def __init__(self, spans: list):
+        self.spans = sorted(((s, s + d, name) for name, s, d in spans),
+                            key=lambda x: (x[0], x[0] - x[1]))
+        self.starts = [s for s, _, _ in self.spans]
+        self.reach = list(itertools.accumulate((e for _, e, _ in self.spans), max))
+
+    def at(self, t: float):
+        k = bisect.bisect_right(self.starts, t) - 1
+        while k >= 0 and self.reach[k] >= t:  # a span opened by k still runs at t
+            _, e, name = self.spans[k]
+            if e >= t:
+                return name
+            k -= 1
+        return None
+
+
 def is_kernel(op: list) -> bool:
     """A Pallas kernel launch (the sweep kernel is the only one the
     service runs)."""
     return op[3] == "tpu_custom_call"
 
 
-def _host_label(host: list, t: float) -> str:
-    covering = {name for name, s, d in host if s <= t <= s + d}
-    for key, label in GAP_LABELS:
-        if key in covering:
-            return label
-    return "client: between steps"
-
-
 def reduce(rec: dict) -> dict:
     """Busy union, kernel time, op totals and labelled idle gaps of a
-    normalized record.  Times in seconds; per-device values averaged."""
+    normalized record, and for each host span name its durations inside
+    the window (``spans``) and the idle time under its intervals
+    (``idle_under``).  An idle gap is labelled with the innermost program
+    span open at its midpoint, else the innermost benchmark annotation,
+    else ``bench.window``.  Times in seconds; per-device values averaged."""
     w0, w1 = rec["window"]
     window_s = (w1 - w0) / 1e9
     devs = rec["devices"]
     if not devs:
         raise RuntimeError("the trace holds no device operations")
+    host = rec["host"]
+    names = sorted({n for n, _, _ in host})
+    program = _Innermost([h for h in host if not h[0].startswith(BENCH_PREFIX)])
+    bench = _Innermost([h for h in host if h[0].startswith(BENCH_PREFIX)])
+    covered = {name: _union([[max(s, w0), min(s + d, w1)] for n, s, d in host
+                             if n == name and min(s + d, w1) > max(s, w0)])
+               for name in names}
     busy = kernel = 0.0
     ops, gaps = {}, {}
+    idle_under = dict.fromkeys(names, 0.0)
     longest = []
     for evs in devs.values():
         clipped = [[e[0], max(e[1], w0), min(e[1] + e[2], w1), e] for e in evs]
@@ -205,11 +227,14 @@ def reduce(rec: dict) -> dict:
             if is_kernel(op):
                 kernel += (e - s) / 1e9
         edges = [w0] + [x for iv in u for x in iv] + [w1]
-        for s, e in zip(edges[::2], edges[1::2]):
-            if e > s:
-                label = _host_label(rec["host"], (s + e) / 2)
-                gaps[label] = gaps.get(label, 0.0) + (e - s) / 1e9
-                longest.append((e - s) / 1e9)
+        idle = [[s, e] for s, e in zip(edges[::2], edges[1::2]) if e > s]
+        for s, e in idle:
+            mid = (s + e) / 2
+            label = program.at(mid) or bench.at(mid) or WINDOW
+            gaps[label] = gaps.get(label, 0.0) + (e - s) / 1e9
+            longest.append((e - s) / 1e9)
+        for name in names:
+            idle_under[name] += _overlap(idle, covered[name]) / 1e9
     nd = len(devs)
     top_ops = sorted(ops.items(), key=lambda kv: -kv[1])[:10]
     top_gaps = sorted(gaps.items(), key=lambda kv: -kv[1])[:10]
@@ -221,8 +246,9 @@ def reduce(rec: dict) -> dict:
         device_ops=[[k, v / nd] for k, v in top_ops],
         idle_gaps=[[k, v / nd] for k, v in top_gaps],
         longest_gap_s=max(longest, default=0.0),
-        spans={name: [d / 1e9 for n, s, d in rec["host"] if n == name and w0 <= s and s + d <= w1]
-               for name in HOST_SPANS},
+        spans={name: [d / 1e9 for n, s, d in host if n == name and w0 <= s and s + d <= w1]
+               for name in names},
+        idle_under={name: v / nd for name, v in idle_under.items()},
         counters=rec.get("counters", {}),
     )
 

@@ -1,12 +1,12 @@
 """The one traffic generator: turns a mix's parameters (a JSON file under
-``bench/traffic/``) and the seed into a stream of job specs.
+``bench/traffic/``) and the seed into job specs.
 
-A spec is a plain dict the harness turns into the program's job:
-
-* ``{"kind": "pt", "seed", "betas", "rounds", "sweeps_per_round"}``
-* ``{"kind": "anneal", "seed", "schedule": [(sweeps, beta), ...],
-  "user", "instance", "arrival"}`` (``arrival`` in seconds from the start
-  of the stream, open loops only)
+A mix's ``kind`` names the module under ``bench/kinds/`` that makes its
+specs (`spec.kind`).  A kind of single-slot jobs takes them from a
+`Stream`, which draws each job's budget, user, instance, seed and, in an
+open loop, arrival, and leaves the kind to shape the budget into the
+job's own fields.  A spec is a plain dict; ``arrival`` is in seconds
+from the start of the stream, open loops only.
 
 Sizes and gaps are drawn so that every seed gets the same work in another
 order: budgets run through every allowed value once per block, and open
@@ -20,24 +20,13 @@ import numpy as np
 SEED_SPACE = 2**31  # job seeds stay far below the 32-bit generator seeds
 
 
-def ladder_specs(mix: dict, chips: int, seed: int) -> list:
-    rng = np.random.default_rng([seed, 1])
-    if mix["spacing"] != "geometric":
-        raise ValueError(f"unknown beta spacing {mix['spacing']!r}")
-    betas = np.geomspace(mix["beta_min"], mix["beta_max"], mix["rungs"]).astype(np.float32)
-    per = mix["sweeps_per_round"]
-    if mix["sweeps"] % per:
-        raise ValueError("sweeps must be a whole number of rounds")
-    return [dict(kind="pt", seed=int(rng.integers(SEED_SPACE)), betas=betas,
-                 rounds=mix["sweeps"] // per, sweeps_per_round=per)
-            for _ in range(mix["ladders_per_chip"] * chips)]
+class Stream:
+    """Endless seeded stream of single-slot jobs; ``shape(mix, budget)``
+    gives a job's own fields (it draws nothing)."""
 
-
-class AnnealStream:
-    """Endless seeded stream of single-slot annealing ramps."""
-
-    def __init__(self, mix: dict, instances: int, seed: int):
+    def __init__(self, mix: dict, instances: int, seed: int, shape):
         self.mix = mix
+        self.shape = shape
         self.rng = np.random.default_rng([seed, 2])
         step = mix["sweeps_per_step"]
         lo, hi = mix["budget_min"], mix["budget_max"]
@@ -71,15 +60,11 @@ class AnnealStream:
         return float(self._gaps.pop())
 
     def next(self) -> dict:
-        mix = self.mix
-        budget = self._next_budget()
-        steps = budget // mix["sweeps_per_step"]
-        betas = np.linspace(mix["beta_start"], mix["beta_end"], steps)
+        spec = self.shape(self.mix, self._next_budget())
         user = int(self.rng.choice(len(self.user_p), p=self.user_p))
         inst = user * self.per_user + int(self.rng.integers(self.per_user))
-        spec = dict(kind="anneal", seed=int(self.rng.integers(SEED_SPACE)),
-                    schedule=[(mix["sweeps_per_step"], float(b)) for b in betas],
-                    user=f"user{user:02d}", instance=inst, arrival=None)
+        spec.update(seed=int(self.rng.integers(SEED_SPACE)), user=f"user{user:02d}",
+                    instance=inst, arrival=None)
         if self.gaps is not None:
             self.clock += self._next_gap()
             spec["arrival"] = self.clock

@@ -1,5 +1,6 @@
 """Mean host time per scheduling step of the program's own
-``sched.admit`` telemetry span, over the steps of the traced window."""
+``sched.admit`` span, in ms, over the steps of the traced window, read
+from the profiler's host plane."""
 
 
 def read(ctx):

@@ -147,6 +147,10 @@ def main(argv=None) -> int:
                 f"{t['kernel_s'] / updates * 1e9!r} ns of kernel time per spin update "
                 f"on one chip; roofline against HBM alone ({chip_peaks['source']}), "
                 "no f32 vector peak is published")
+        for name, spans in t["spans"].items():
+            if spans:
+                log(f"span {name}: {len(spans)} x {sum(spans) / len(spans) * 1e3!r} ms, "
+                    f"device idle under it {t['idle_under'][name]!r} s")
         log(f"traced window: {t['counters']}; longest idle gap {t['longest_gap_s']!r} s")
     else:
         result["metrics"] = end_to_end(spec.metrics_of(bench, args.workload, "end_to_end"), rec)

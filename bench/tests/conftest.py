@@ -55,17 +55,25 @@ def tiny(name: str):
 _COUNTER = None
 
 
-def run_tiny(name: str, seed: int = LARGE_SEED, seconds: float = 0.6) -> dict:
+def run_cell(c: dict, cfg: dict, mix: dict, seed: int = LARGE_SEED, seconds: float = 0.6) -> dict:
     global _COUNTER
     if _COUNTER is None:
         _COUNTER = cell.CompileCounter()
-    c, cfg, mix = tiny(name)
     return cell.run(c, cfg, mix, seed, seconds, False, time.perf_counter(), _COUNTER)
+
+
+def run_tiny(name: str, seed: int = LARGE_SEED, seconds: float = 0.6) -> dict:
+    return run_cell(*tiny(name), seed, seconds)
 
 
 @pytest.fixture
 def tiny_run():
     return run_tiny
+
+
+@pytest.fixture
+def cell_run():
+    return run_cell
 
 
 @pytest.fixture

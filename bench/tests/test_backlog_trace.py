@@ -3,8 +3,8 @@
 its metrics read, and its host plane holds the program's own spans of
 admission, the anneal hook and retirement, each inside a scheduling
 step.  Those spans are profiler annotations the program writes
-(`Telemetry.span`); the normalized record keeps them from the trace's
-host plane with no rescale."""
+(`Telemetry.span`); the normalized record keeps them, and the step's
+own span, from the trace's host plane on the profiler's clock."""
 
 from __future__ import annotations
 
@@ -19,9 +19,6 @@ BENCH = spec.load_benchmark()
 # The spans of one scheduling step of this cell, below ``sched.step``.
 STEP_SPANS = ("anneal.hook", "engine.set_betas", "job.finalize", "engine.extract",
               "job.init", "engine.splice", "engine.dispatch", "engine.wait")
-# The steps are telemetry spans moved onto the profiler's clock by a
-# rescale that read 14-20 us off on the chip: 0.1 ms of room.
-TOL_NS = 100_000
 
 
 @pytest.fixture(scope="module")
@@ -34,7 +31,7 @@ def _inside(rec, name) -> list:
     holds it."""
     w0, w1 = rec["window"]
     outer = [(s, s + d) for n, s, d in rec["host"] if n == "sched.step"]
-    return [any(a - TOL_NS <= s and s + d <= b + TOL_NS for a, b in outer)
+    return [any(a <= s and s + d <= b for a, b in outer)
             for n, s, d in rec["host"] if n == name and w0 <= s and s + d <= w1]
 
 

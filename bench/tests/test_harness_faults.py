@@ -7,7 +7,7 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
-from harness import check, reference
+from harness import check, reference, spec
 
 CELLS = ["ladders", "anneal-backlog"]
 
@@ -65,15 +65,15 @@ def test_a_broken_timed_path_is_not_correct(tiny_run, monkeypatch, name, fault):
 
 @pytest.mark.parametrize("seed", [3, 2**31 + 5])
 def test_the_bfloat16_control_is_not_correct(tiny_cell, seed):
-    import control
     from harness import models
 
     c, cfg, mix = tiny_cell("ladders")
+    kind = spec.kind(mix["kind"])
     insts = models.instances(cfg, seed)
     lat = reference.Lattice(insts[0]["n"], insts[0]["L"], 4, insts[0]["space_nbr"])
-    ctrl = control.ladder_outputs(lat, insts, mix, seed, 256, reference.jnp.bfloat16)
-    ok, checks = check.verdict(check.numbers(ctrl, lat, insts, 0))
+    ctrl = kind.control(lat, insts, mix, seed, reference.jnp.bfloat16, 256)
+    ok, checks = check.verdict(check.numbers(ctrl, lat, insts, 0, kind), kind)
     assert not ok and checks["spins_differ"]["value"] > 0
-    same = control.ladder_outputs(lat, insts, mix, seed, 256, reference.f32)
-    ok, _ = check.verdict(check.numbers(same, lat, insts, 0))
+    same = kind.control(lat, insts, mix, seed, reference.f32, 256)
+    ok, _ = check.verdict(check.numbers(same, lat, insts, 0, kind), kind)
     assert ok

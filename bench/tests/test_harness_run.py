@@ -23,3 +23,26 @@ def test_open_loop_times_every_due_job(tiny_run):
     assert rec["due"] and not rec["lost"]
     assert len(rec["latencies"]) == len(rec["due"])
     assert min(rec["latencies"]) > 0
+
+
+def test_a_closed_set_whose_jobs_all_retired_is_still_served_and_compared(tiny_cell):
+    """Ladders that retire before the close: a tick returns at once, and
+    their results are compared against the reference."""
+    from harness import cell, check, models, reference, spec
+
+    c, cfg, mix = tiny_cell("ladders")
+    mix.update(sweeps=32)
+    kind = spec.kind(mix["kind"])
+    insts = models.instances(cfg, 7)
+    served = cell.Served(cfg, 1, insts, kind)
+    loop = cell.Loop(served, mix, 1, 7)
+    loop.start()
+    for _ in range(16):
+        loop.tick()
+    assert len(loop.retired) == len(served.jobs) == 2
+    loop.tick()  # nothing active, queued or due
+    out = kind.outputs(served, loop, [], mix, None)
+    lat = reference.Lattice(insts[0]["n"], insts[0]["L"], served.server.engine.V,
+                            insts[0]["space_nbr"])
+    res = check.compare(out, lat, insts, 0, kind)
+    assert res["correct"] and res["compared"] == 8, res
