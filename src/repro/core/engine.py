@@ -458,6 +458,7 @@ class SweepEngine:
         self._extract_jit = None
         self._splice_tables_jit = None
         self._extract_tables_jit = None
+        self._set_tau_jit = None
         self._energies_jit = None
         # Per-model slot-table cache: admission is on the serving fast
         # path and a server's tenant set recurs, so a model's tables are
@@ -1529,8 +1530,94 @@ class SweepEngine:
 
     def model_of(self, b: int) -> ising.LayeredModel | None:
         """The model slot ``b`` currently sweeps (None if its tables were
-        last written by a raw `splice_slot_tables`)."""
+        last written by a raw `splice_slot_tables` or `set_slot_tau`)."""
         return self.models[b] if self.multi else self.model
+
+    def set_slot_tau(self, carry: SweepCarry, slots, tau_rows) -> SweepCarry:
+        """Rewrite the tau couplings of many slots in ONE jitted call
+        (multi only): row ``k`` of ``tau_rows`` (``(len(slots), n)``)
+        becomes slot ``slots[k]``'s ``tau_J``, and twice it its
+        ``tau_J2``.  Returns the carry.
+
+        Slot indices are traced and padded to the physical batch with an
+        out-of-range index whose writes are dropped, so one executable
+        serves every call, whatever the number of slots.  The a4 kernels
+        carry incremental fields, so the same call rebuilds those slots'
+        ``h_tau`` from their spins (`metropolis.lane_h_tau`); the cb
+        kernels recompute fields from the tables on entry and leave the
+        carry alone.  The slots' recorded models become None, as after a
+        raw `splice_slot_tables`: their tables match no model object.
+        """
+        if not self.multi:
+            raise ValueError("set_slot_tau needs a multi-tenant engine")
+        slots = [int(b) for b in slots]
+        rows = np.asarray(tau_rows, np.float32)
+        n = self.model.n
+        if rows.shape != (len(slots), n):
+            raise ValueError(
+                f"tau_rows must have shape ({len(slots)}, {n}), got {rows.shape}"
+            )
+        if len(set(slots)) != len(slots) or not all(
+            0 <= b < self.batch for b in slots
+        ):
+            raise ValueError(
+                f"slots must be distinct and in range for batch {self.batch}: "
+                f"{slots}"
+            )
+        if not slots:
+            return carry
+        tel = self.telemetry
+        with tel.span("engine.set_tau", cat="engine"):
+            tel.counter("engine.table_rewrites").add(1)
+            P_ = self._phys_batch
+            idx = np.full((P_,), P_, np.int32)  # padding: dropped writes
+            idx[: len(slots)] = self.phys_slots(slots)
+            pad = np.zeros((P_, n), np.float32)
+            pad[: len(slots)] = rows
+            if self._set_tau_jit is None:
+                self._set_tau_jit = self._build_set_tau()
+            if self.rung == "a4":
+                self.slot_tables, carry = self._set_tau_jit(
+                    self.slot_tables, carry, idx, pad
+                )
+            else:
+                self.slot_tables = self._set_tau_jit(self.slot_tables, idx, pad)
+            models = list(self.models)
+            for b in slots:
+                models[b] = None
+            self.models = tuple(models)
+        return carry
+
+    def _build_set_tau(self) -> Callable:
+        def write(tabs, idx, rows):
+            return dict(
+                tabs,
+                tau_J=tabs["tau_J"].at[idx].set(rows, mode="drop"),
+                tau_J2=tabs["tau_J2"].at[idx].set(f32(2.0) * rows, mode="drop"),
+            )
+
+        mesh = self.mesh
+        if self.rung != "a4":
+            kw = {"out_shardings": self._table_shardings()} if mesh else {}
+            return jax.jit(write, **kw)
+        n = self.model.n
+
+        def write_rebuild(tabs, carry, idx, rows):
+            spins = carry.spins.at[idx].get(mode="fill", fill_value=0.0)
+            h_tau = jax.vmap(lambda sp, t: metropolis.lane_h_tau(sp, t, n))(
+                spins, rows
+            )
+            carry = carry._replace(
+                h_tau=carry.h_tau.at[idx].set(h_tau, mode="drop")
+            )
+            return write(tabs, idx, rows), carry
+
+        kw = (
+            {"out_shardings": (self._table_shardings(), self._carry_shardings())}
+            if mesh
+            else {}
+        )
+        return jax.jit(write_rebuild, **kw)
 
 
 # -----------------------------------------------------------------------------

@@ -296,10 +296,19 @@ def lane_h_eff(
     hs = jnp.broadcast_to(h[None, :, None].astype(f32), s.shape)
     for d in range(base_nbr.shape[1]):
         hs = hs + base_J[None, :, d, None] * s[:, base_nbr[:, d], :]
+    return hs.reshape(rows, V), lane_h_tau(spins, tau_J, n)
+
+
+def lane_h_tau(spins: jax.Array, tau_J: jax.Array, n: int) -> jax.Array:
+    """The tau half of `lane_h_eff`: ``tau_J * (down + up)`` per spin of a
+    (rows, V) lane tile.  Exact in float32 (``down + up`` is -2, 0 or 2),
+    so a rebuild under new tau couplings equals the incremental fields a
+    sweep would have carried under them."""
+    rows, V = spins.shape
+    s = spins.reshape(rows // n, n, V)
     down = jnp.concatenate([jnp.roll(s[-1:], 1, axis=-1), s[:-1]], axis=0)
     up = jnp.concatenate([s[1:], jnp.roll(s[:1], -1, axis=-1)], axis=0)
-    ht = tau_J[None, :, None] * (down + up)
-    return hs.reshape(rows, V), ht.reshape(rows, V)
+    return (tau_J[None, :, None] * (down + up)).reshape(rows, V)
 
 
 def class_coupling_slices(classes, h_b, space_J_b, tau_J_b, n: int):

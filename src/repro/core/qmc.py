@@ -42,6 +42,25 @@ class QMCProblem:
     space_J: np.ndarray  # (n, SD)
     L: int  # Trotter slices
 
+    @classmethod
+    def of(cls, model: ising.LayeredModel) -> "QMCProblem":
+        """The problem whose ``model.L`` Trotter slices ``model`` lays out:
+        its fields and couplings (its tau couplings and beta are not part
+        of the problem)."""
+        return cls(h=model.h, space_nbr=model.space_nbr,
+                   space_J=model.space_J, L=model.L)
+
+    def energy(self, assign: np.ndarray) -> float:
+        """Problem energy -sum h s - sum_edges J s s of one (n,) +-1
+        assignment, in float64."""
+        s = np.asarray(assign, np.float64)
+        e = -float(np.sum(self.h.astype(np.float64) * s))
+        for d in range(self.space_nbr.shape[1]):
+            e -= 0.5 * float(np.sum(
+                self.space_J[:, d].astype(np.float64) * s * s[self.space_nbr[:, d]]
+            ))
+        return e
+
     def layered_model(self, beta: float, gamma: float) -> ising.LayeredModel:
         """Instantiate the classical layered model for one (beta, gamma).
 
@@ -60,6 +79,13 @@ class QMCProblem:
             tau_J=np.full((n,), jt, dtype=np.float32),
             beta=float(beta),
         )
+
+
+def layer_majority(spins: np.ndarray, n: int, L: int) -> np.ndarray:
+    """The annealer's answer: per site, the sign of its mean over the L
+    flat layer-major Trotter slices (ties read +1)."""
+    layers = np.asarray(spins, np.float32).reshape(L, n)
+    return np.where(layers.mean(axis=0) >= 0, 1.0, -1.0).astype(np.float32)
 
 
 def random_problem(n: int, L: int, *, seed: int = 0, degree: int = 5) -> QMCProblem:

@@ -9,7 +9,7 @@ Jobs pack into replica slots of ONE resident engine; every chunk of
 sweeps is a single (on pallas: fused) launch for all of them.
 """
 
-from repro.serve_mc.jobs import AnnealJob, JobResult, PTJob
+from repro.serve_mc.jobs import AnnealJob, JobResult, PTJob, SQAJob
 from repro.serve_mc.scheduler import (
     AdaptiveChunker,
     AdmissionPolicy,
@@ -32,6 +32,7 @@ __all__ = [
     "PlacementPlanner",
     "PriorityBackfillPolicy",
     "ProfilerError",
+    "SQAJob",
     "SampleServer",
     "ServeConfig",
     "SlotPool",

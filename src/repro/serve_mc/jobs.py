@@ -11,16 +11,22 @@ boundaries, where the job's ``on_segment`` hook runs:
 
   * `AnnealJob`   — one slot; a piecewise-constant anneal schedule.  The
     hook rewrites the slot's beta to the next segment's value.
+  * `SQAJob`      — one slot; simulated quantum annealing, a transverse
+    field ramp at fixed beta.  The hook hands the next Gamma's tau
+    couplings to the server, which rewrites every due slot's tau row in
+    one engine call after the step's hooks (multi-tenant servers only).
   * `PTJob`       — R slots; every segment is one parallel-tempering
     round.  The hook is one `tempering.swap_slots` call over the shared
     carry: it reads only the job's spins and betas and writes the swapped
     betas back, so a tempering round is literally "one scheduled chunk +
     swap" and shares fused launches with whatever else is resident.
 
-Both job types reproduce their standalone counterparts bit for bit: an
+Every job type reproduces its standalone counterpart bit for bit: an
 `AnnealJob` equals a solo ``SweepEngine`` run with the same seed and
-schedule, a `PTJob` equals `tempering.run_parallel_tempering` — no matter
-which slots they land in or what runs beside them (tests/test_serve_mc.py).
+schedule, a `PTJob` equals `tempering.run_parallel_tempering`, an `SQAJob`
+a solo engine swept under each Gamma's model in turn — no matter which
+slots they land in or what runs beside them (tests/test_serve_mc.py,
+tests/test_sqa.py).
 
 On a MULTI-TENANT server (``SampleServer(..., multi_tenant=True)``) either
 job may additionally carry its OWN `LayeredModel` (same lattice topology
@@ -42,7 +48,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import engine as sweep_engine
-from repro.core import ising, mt19937, observables, tempering
+from repro.core import ising, mt19937, observables, qmc, tempering
 
 
 class JobResult(NamedTuple):
@@ -86,6 +92,7 @@ class _ScheduledJob:
 
     num_slots = 1
     kind = "job"  # telemetry label (job lifecycle trace events)
+    rewrites_tables = False  # hooks rewrite the slots' coupling tables
 
     def __init__(
         self,
@@ -377,6 +384,126 @@ class AnnealJob(_ScheduledJob):
             chunks=self.chunks,
             extras={
                 "final_beta": final_beta,
+                "preemptions": self.preemptions,
+            },
+        )
+
+
+class SQAJob(_ScheduledJob):
+    """Simulated quantum annealing: one slot, one seed, a transverse-field
+    ramp over the Trotter slices of one problem (path-integral Monte
+    Carlo; Santoro et al., Science 295, 2427 (2002)).
+
+    ``model`` is the problem: its fields, couplings and slice count L
+    (its tau couplings and beta play no part); None means the server's
+    base model.  Beta stays ``beta``; segment k (``sweeps_per_gamma``
+    sweeps) sweeps `qmc.QMCProblem.layered_model(beta, gammas[k])`: h and
+    J over L, and tau couplings from ``gammas[k]``.  At each boundary the
+    hook hands the next Gamma's tau row to the server (`queue_tau`),
+    which writes every due row of the step in one `set_slot_tau` call.
+    The slot's tables change as the job runs, so it needs a
+    multi-tenant server.  The result is the final slices and their
+    layered energy under the last Gamma; ``extras`` hold that Gamma,
+    the layer-majority assignment and its problem energy.
+    """
+
+    kind = "sqa"
+    rewrites_tables = True
+
+    def __init__(
+        self,
+        seed: int,
+        beta: float,
+        gammas: Sequence[float],
+        sweeps_per_gamma: int,
+        model: ising.LayeredModel | None = None,
+        priority: int = 0,
+        user: str | None = None,
+    ):
+        gammas = [float(g) for g in gammas]
+        super().__init__(
+            [int(sweeps_per_gamma)] * len(gammas), model=model,
+            priority=priority, user=user,
+        )
+        self.seed = int(seed)
+        self.beta = float(beta)
+        self.gammas = gammas
+
+    def snapshot_state(self) -> tuple[dict, dict]:
+        meta, arrays = self._snapshot_base()
+        meta["seed"] = self.seed
+        meta["beta"] = self.beta
+        meta["sweeps_per_gamma"] = self._segments[0]
+        arrays["gammas"] = np.asarray(self.gammas, np.float64)
+        return meta, arrays
+
+    @classmethod
+    def from_snapshot(cls, meta: dict, arrays: dict, model=None):
+        job = cls(
+            meta["seed"],
+            meta["beta"],
+            arrays["gammas"].tolist(),
+            meta["sweeps_per_gamma"],
+            model=model,
+            priority=meta["priority"],
+            user=meta["user"],
+        )
+        job._restore_base(meta, arrays)
+        return job
+
+    def problem(self, server) -> qmc.QMCProblem:
+        return qmc.QMCProblem.of(
+            self.model if self.model is not None else server.engine.model
+        )
+
+    def current_gamma(self) -> float:
+        return self.gammas[min(self._seg, len(self.gammas) - 1)]
+
+    def tau_row(self, server) -> np.ndarray:
+        """The slot's tau couplings for the current Gamma: the
+        ``tau_J`` of `model_on`, without building the model."""
+        pb = self.problem(server)
+        jt = qmc.tau_coupling(self.beta, self.current_gamma(), pb.L)
+        return np.full(pb.h.shape, jt, dtype=np.float32)
+
+    def model_on(self, server) -> ising.LayeredModel:
+        """The layered model of the current Gamma (the last one once the
+        ramp is done): what the slot sweeps now, and what admission
+        splices or a resumed slot's tables hold."""
+        return self.problem(server).layered_model(self.beta, self.current_gamma())
+
+    # -- scheduler interface --------------------------------------------------
+
+    def init_carries(self, server) -> list[sweep_engine.SweepCarry]:
+        return [
+            server.engine.init_slot_carry(
+                seed=self.seed, beta=self.beta, model=self.model_on(server)
+            )
+        ]
+
+    def on_segment(self, server, carry, slots):
+        with server.telemetry.span("sqa.hook"):
+            if not self.done:
+                server.queue_tau(slots[0], self.tau_row(server))
+            return carry
+
+    def finalize(self, server, slots) -> JobResult:
+        eng, m = server.engine, self.model_on(server)
+        with server.telemetry.span("engine.extract", cat="engine"):
+            spins = eng.spins_flat(eng.extract_slot(server.carry, slots[0]))[0]
+        pb = self.problem(server)
+        assign = qmc.layer_majority(spins, m.n, m.L)
+        return JobResult(
+            jid=self.jid,
+            spins=spins,
+            energy=observables.energies(m, spins),
+            magnetization=observables.magnetization(spins),
+            sweeps_done=self.sweeps_done,
+            chunks=self.chunks,
+            extras={
+                "final_gamma": self.current_gamma(),
+                "assignment": assign,
+                "problem_energy": pb.energy(assign),
                 "preemptions": self.preemptions,
             },
         )

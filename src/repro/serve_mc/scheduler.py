@@ -1040,6 +1040,10 @@ class SampleServer:
         self._c_migrations = tel.counter("sched.rebalance_migrations")
         self._c_swap_local = tel.counter("pt.swap_local")
         self._c_swap_cross = tel.counter("pt.swap_cross")
+        # Tau rows the step's hooks handed over (`queue_tau`), written
+        # after the hook loop by one `engine.set_slot_tau` call.
+        self._due_tau: list = []
+        self._c_tau_rows = tel.counter("sqa.tau_rows")
         self.stream = stream
         # Chunk sizes already compiled (num_sweeps is a static jit arg):
         # a launch whose size is not in here pays compilation, and its
@@ -1163,6 +1167,12 @@ class SampleServer:
             )
         if job.jid is not None:
             raise ValueError(f"job already submitted (jid={job.jid})")
+        if job.rewrites_tables and not self.multi_tenant:
+            raise ValueError(
+                f"{type(job).__name__} rewrites its slots' coupling tables "
+                "as it runs; this server is single-model — construct it "
+                "with multi_tenant=True"
+            )
         if getattr(job, "model", None) is not None:
             if not self.multi_tenant:
                 raise ValueError(
@@ -1232,6 +1242,13 @@ class SampleServer:
             self._park(job)
         for job, slots in admits:
             self._place(job, slots)
+
+    def queue_tau(self, slot: int, tau_row) -> None:
+        """Hand over slot ``slot``'s next tau couplings (an (n,) row) from
+        a job hook; the step writes every row handed over in one
+        `engine.set_slot_tau` call after its hook loop, before the next
+        launch."""
+        self._due_tau.append((int(slot), tau_row))
 
     def _park(self, job) -> None:
         """Checkpoint-preempt an active job: extract each slot's carry
@@ -1581,8 +1598,8 @@ class SampleServer:
             self._c_total.add(chunk * self.slots)
             # Advance all jobs first, THEN tap the stream: sweeps_done is
             # current and a retiring job's final chunk is still sampled
-            # (hooks only rewrite betas, never spins, so pre-hook spins
-            # are the post-chunk spins).
+            # (hooks rewrite betas and coupling tables, never spins, so
+            # pre-hook spins are the post-chunk spins).
             boundary = [
                 jid
                 for jid in list(self._active)
@@ -1609,6 +1626,13 @@ class SampleServer:
                         chunks=job.chunks,
                         preemptions=job.preemptions,
                     )
+            if self._due_tau:
+                # Every tau row the hooks handed over, in one engine call
+                # (one executable, whatever their number).
+                slots, rows = zip(*self._due_tau)
+                self._due_tau = []
+                self.carry = self.engine.set_slot_tau(self.carry, slots, rows)
+                self._c_tau_rows.add(len(slots))
             if (
                 self.snapshot_every_sweeps
                 and self.sweeps_elapsed - self._last_snapshot_sweep

@@ -311,7 +311,7 @@ def restore_server(
     cadence; pass ``snapshot_manager``/``snapshot_every_sweeps`` to
     redirect or disable them.
     """
-    from repro.serve_mc.jobs import AnnealJob, PTJob
+    from repro.serve_mc.jobs import AnnealJob, PTJob, SQAJob
     from repro.serve_mc.scheduler import AdaptiveChunker, SampleServer
 
     mgr = source if isinstance(source, CheckpointManager) else CheckpointManager(str(source))
@@ -389,7 +389,7 @@ def restore_server(
     server.carry = server.engine.splice_pool(pool)
 
     # Jobs: queued (in recorded queue order) then active.
-    kinds = {"anneal": AnnealJob, "pt": PTJob}
+    kinds = {"anneal": AnnealJob, "pt": PTJob, "sqa": SQAJob}
     for entry in extra["jobs"]:
         meta = entry["meta"]
         key = f"job/{meta['jid']}"

@@ -172,3 +172,20 @@ def test_pt_swap_slots_compiles_for_v5e(one_chip, model):
     betas, swap_rng, acc, prop = compiled.out_info
     assert betas.shape == (SLOTS,) and swap_rng.shape == (mt.N,)
     assert acc.shape == prop.shape == ()
+
+
+@pytest.mark.parametrize("rung", ["a4", "cb"])
+def test_slot_tau_rewrite_compiles_for_v5e(one_chip, model, rung):
+    """The one table-rewrite call of a step (`set_slot_tau`), padded to the
+    whole pool; on the a4 rung it also rebuilds the slots' tau fields."""
+    eng = SweepEngine.create([model] * SLOTS, rung=rung, backend="jnp", V=LANES)
+    fn = eng._build_set_tau()
+    tabs = {k: _shape(one_chip, v.shape, v.dtype) for k, v in eng.slot_tables.items()}
+    idx, rows = _shape(one_chip, (SLOTS,), jnp.int32), _shape(one_chip, (SLOTS, N))
+    if rung == "a4":
+        carry = _carry_shapes(eng, SweepCarry(*[one_chip] * 5))
+        tabs_out, carry_out = fn.lower(tabs, carry, idx, rows).compile().out_info
+        assert carry_out.h_tau.shape == carry.h_tau.shape
+    else:
+        tabs_out = fn.lower(tabs, idx, rows).compile().out_info
+    assert tabs_out["tau_J"].shape == tabs_out["tau_J2"].shape == (SLOTS, N)
