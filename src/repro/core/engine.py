@@ -459,6 +459,7 @@ class SweepEngine:
         self._splice_tables_jit = None
         self._extract_tables_jit = None
         self._set_tau_jit = None
+        self._set_betas_jit = None
         self._energies_jit = None
         # Per-model slot-table cache: admission is on the serving fast
         # path and a server's tenant set recurs, so a model's tables are
@@ -1410,14 +1411,64 @@ class SweepEngine:
 
     def set_slot_betas(self, carry: SweepCarry, slots, betas) -> SweepCarry:
         """Rewrite the betas of the given slots (anneal-schedule advance,
-        tempering swaps) without touching spins, fields, or RNG."""
-        with self.telemetry.span("engine.set_betas", cat="engine"):
-            idx = jnp.asarray(self.phys_slots(slots).astype(np.int32))
-            vals = jnp.asarray(betas, f32)
-            new = carry.betas.at[idx].set(vals)
-            if self.mesh is not None:  # keep the betas row sharded
-                new = jax.device_put(new, NamedSharding(self.mesh, P("data")))
-            return carry._replace(betas=new)
+        tempering swaps) without touching spins, fields, or RNG: beta
+        ``betas[k]`` becomes slot ``slots[k]``'s, in ONE jitted call.
+
+        Slot indices are traced and padded to the physical batch with an
+        out-of-range index whose writes are dropped, so one executable
+        serves every call, whatever the number of slots.  On a mesh the
+        betas row stays sharded over ``data``.
+        """
+        slots = self._check_slots(slots)
+        vals = np.asarray(betas, np.float32)
+        if vals.shape != (len(slots),):
+            raise ValueError(
+                f"betas must have shape ({len(slots)},), got {vals.shape}"
+            )
+        if not slots:
+            return carry
+        tel = self.telemetry
+        with tel.span("engine.set_betas", cat="engine"):
+            tel.counter("engine.beta_rewrites").add(1)
+            idx, pad = self._padded_rows(slots, vals)
+            if self._set_betas_jit is None:
+                self._set_betas_jit = self._build_set_betas()
+            return carry._replace(
+                betas=self._set_betas_jit(carry.betas, idx, pad)
+            )
+
+    def _build_set_betas(self) -> Callable:
+        kw = (
+            {"out_shardings": NamedSharding(self.mesh, P("data"))}
+            if self.mesh is not None
+            else {}
+        )
+        return jax.jit(
+            lambda row, idx, vals: row.at[idx].set(vals, mode="drop"), **kw
+        )
+
+    def _check_slots(self, slots) -> list[int]:
+        """``slots`` as ints, or ValueError unless distinct and in range."""
+        slots = [int(b) for b in slots]
+        if len(set(slots)) != len(slots) or not all(
+            0 <= b < self.batch for b in slots
+        ):
+            raise ValueError(
+                f"slots must be distinct and in range for batch {self.batch}: "
+                f"{slots}"
+            )
+        return slots
+
+    def _padded_rows(self, slots: list[int], rows: np.ndarray):
+        """Physical row indices of ``slots`` and their ``rows``, padded to
+        the physical batch: padding indices point past the batch (their
+        writes are dropped) and padding rows are zero."""
+        P_ = self._phys_batch
+        idx = np.full((P_,), P_, np.int32)
+        idx[: len(slots)] = self.phys_slots(slots)
+        pad = np.zeros((P_,) + rows.shape[1:], np.float32)
+        pad[: len(slots)] = rows
+        return idx, pad
 
     # -- per-slot model tables (the multi-tenant admit API) --------------------
     #
@@ -1550,30 +1601,19 @@ class SweepEngine:
         """
         if not self.multi:
             raise ValueError("set_slot_tau needs a multi-tenant engine")
-        slots = [int(b) for b in slots]
+        slots = self._check_slots(slots)
         rows = np.asarray(tau_rows, np.float32)
         n = self.model.n
         if rows.shape != (len(slots), n):
             raise ValueError(
                 f"tau_rows must have shape ({len(slots)}, {n}), got {rows.shape}"
             )
-        if len(set(slots)) != len(slots) or not all(
-            0 <= b < self.batch for b in slots
-        ):
-            raise ValueError(
-                f"slots must be distinct and in range for batch {self.batch}: "
-                f"{slots}"
-            )
         if not slots:
             return carry
         tel = self.telemetry
         with tel.span("engine.set_tau", cat="engine"):
             tel.counter("engine.table_rewrites").add(1)
-            P_ = self._phys_batch
-            idx = np.full((P_,), P_, np.int32)  # padding: dropped writes
-            idx[: len(slots)] = self.phys_slots(slots)
-            pad = np.zeros((P_, n), np.float32)
-            pad[: len(slots)] = rows
+            idx, pad = self._padded_rows(slots, rows)
             if self._set_tau_jit is None:
                 self._set_tau_jit = self._build_set_tau()
             if self.rung == "a4":

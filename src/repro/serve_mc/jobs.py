@@ -10,7 +10,8 @@ function of sweeps completed), but it always stops exactly at segment
 boundaries, where the job's ``on_segment`` hook runs:
 
   * `AnnealJob`   — one slot; a piecewise-constant anneal schedule.  The
-    hook rewrites the slot's beta to the next segment's value.
+    hook hands the next segment's beta to the server, which rewrites
+    every due slot's beta in one engine call after the step's hooks.
   * `SQAJob`      — one slot; simulated quantum annealing, a transverse
     field ramp at fixed beta.  The hook hands the next Gamma's tau
     couplings to the server, which rewrites every due slot's tau row in
@@ -363,11 +364,9 @@ class AnnealJob(_ScheduledJob):
 
     def on_segment(self, server, carry, slots):
         with server.telemetry.span("anneal.hook"):
-            if self.done:
-                return carry
-            return server.engine.set_slot_betas(
-                carry, slots, [self.current_beta(server)]
-            )
+            if not self.done:
+                server.queue_beta(slots[0], self.current_beta(server))
+            return carry
 
     def finalize(self, server, slots) -> JobResult:
         eng, m = server.engine, self.model_on(server)

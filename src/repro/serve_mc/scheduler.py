@@ -22,9 +22,10 @@ the GPU/TPU never sees:
            cost EWMA and the queue depth pick each chunk from a bounded
            power-of-two menu (latency SLO vs throughput, with a bounded
            jit cache).
-  hooks    jobs whose segment ended run `on_segment` (anneal jobs rewrite
-           their slot's beta; PT jobs run the swap phase over their
-           slots).
+  hooks    jobs whose segment ended run `on_segment` (anneal jobs hand
+           over their slot's next beta and SQA jobs its tau row, each
+           kind written for every due slot in one engine call after the
+           loop; PT jobs run the swap phase over their slots).
   retire   finished jobs are finalized (`core/observables.py` summary of
            the extracted slot), their slots returned to the free list.
 
@@ -1040,10 +1041,13 @@ class SampleServer:
         self._c_migrations = tel.counter("sched.rebalance_migrations")
         self._c_swap_local = tel.counter("pt.swap_local")
         self._c_swap_cross = tel.counter("pt.swap_cross")
-        # Tau rows the step's hooks handed over (`queue_tau`), written
-        # after the hook loop by one `engine.set_slot_tau` call.
+        # Tau rows and betas the step's hooks handed over (`queue_tau`,
+        # `queue_beta`), written after the hook loop by one
+        # `engine.set_slot_tau` and one `engine.set_slot_betas` call.
         self._due_tau: list = []
         self._c_tau_rows = tel.counter("sqa.tau_rows")
+        self._due_betas: list = []
+        self._c_beta_rows = tel.counter("anneal.beta_rows")
         self.stream = stream
         # Chunk sizes already compiled (num_sweeps is a static jit arg):
         # a launch whose size is not in here pays compilation, and its
@@ -1249,6 +1253,12 @@ class SampleServer:
         `engine.set_slot_tau` call after its hook loop, before the next
         launch."""
         self._due_tau.append((int(slot), tau_row))
+
+    def queue_beta(self, slot: int, beta: float) -> None:
+        """Hand over slot ``slot``'s next beta from a job hook; the step
+        writes every beta handed over in one `engine.set_slot_betas`
+        call after its hook loop, before the next launch."""
+        self._due_betas.append((int(slot), beta))
 
     def _park(self, job) -> None:
         """Checkpoint-preempt an active job: extract each slot's carry
@@ -1633,6 +1643,11 @@ class SampleServer:
                 self._due_tau = []
                 self.carry = self.engine.set_slot_tau(self.carry, slots, rows)
                 self._c_tau_rows.add(len(slots))
+            if self._due_betas:
+                slots, betas = zip(*self._due_betas)
+                self._due_betas = []
+                self.carry = self.engine.set_slot_betas(self.carry, slots, betas)
+                self._c_beta_rows.add(len(slots))
             if (
                 self.snapshot_every_sweeps
                 and self.sweeps_elapsed - self._last_snapshot_sweep

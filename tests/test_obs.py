@@ -323,8 +323,15 @@ def test_program_spans_land_in_the_profiler_trace_nested(tmp_path):
                      if ev is not hook and ev[0] in names
                      and _inside(hook, ev)]
             assert inner == ["pt.swap"], inner
+        # An anneal hook only hands its next beta over: the step writes
+        # every due beta in one `engine.set_betas` after the hook loop.
         for ev in by_name.get("engine.set_betas", []):
-            assert any(_inside(h, ev) for h in by_name["anneal.hook"]), ev
+            assert not any(_inside(h, ev) for h in by_name["anneal.hook"]), ev
+            assert any(_inside(s, ev) and h[2] <= ev[1]
+                       for s in steps for h in by_name["anneal.hook"]
+                       if _inside(s, h)), ev
+        for s in steps:
+            assert sum(_inside(s, ev) for ev in by_name.get("engine.set_betas", [])) <= 1
     # `pt.gather` belongs to a ladder spanning devices only.
     assert seen == set(names) - {"pt.gather"}
 

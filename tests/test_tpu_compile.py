@@ -189,3 +189,14 @@ def test_slot_tau_rewrite_compiles_for_v5e(one_chip, model, rung):
     else:
         tabs_out = fn.lower(tabs, idx, rows).compile().out_info
     assert tabs_out["tau_J"].shape == tabs_out["tau_J2"].shape == (SLOTS, N)
+
+
+def test_slot_beta_write_compiles_for_v5e(one_chip, model):
+    """The one beta write of a step (`set_slot_betas`), padded to the
+    anneal backlog's 256-slot multi-tenant pool, cb rung."""
+    backlog = 256
+    eng = SweepEngine.create([model] * backlog, rung="cb", backend="jnp", V=LANES)
+    row = _shape(one_chip, (backlog,))
+    idx = _shape(one_chip, (backlog,), jnp.int32)
+    out = eng._build_set_betas().lower(row, idx, row).compile().out_info
+    assert out.shape == (backlog,) and out.dtype == jnp.float32
